@@ -26,6 +26,7 @@ from repro.experiments import (ExperimentSpec, backends, faultplans,
                                problems, run, schedules, stepsizes,
                                topologies)
 from repro.obs import Tracer, render_summary, write_chrome_trace, write_jsonl
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def _result_tag(result) -> str:
@@ -121,6 +122,7 @@ def main(argv=None) -> int:
     listp = sub.add_parser("list", help="print the component registries")
     listp.set_defaults(fn=_cmd_list)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     try:
         return args.fn(args)
     except BrokenPipeError:

@@ -807,11 +807,11 @@ def run_sweep(spec: ExperimentSpec, axis: str, values: Sequence[Any],
         are not batchable (different shapes, controllers, netsim/launch
         backends, host-only knobs) silently fall back to the serial path.
       * "process" -- fan cells out across OS processes (spawn context, so
-        no forked jax runtime). Meant for the netsim backends, whose
-        event-driven runs are pure host numpy and deterministic for a
-        fixed spec -- results merge back in order, bit-identical to
-        serial. `processes` caps the pool (default: cell count capped by
-        CPU count).
+        no forked jax runtime). netsim cells only: their event-driven
+        runs are pure host numpy and deterministic for a fixed spec, so
+        the children run with JAX_PLATFORMS=cpu and results merge back in
+        order, bit-identical to serial. `processes` caps the pool
+        (default: cell count capped by CPU count).
     """
     cells = [spec.with_value(axis, v) for v in values]
     if parallel in (None, "serial"):
@@ -1005,14 +1005,30 @@ def _process_cell(payload) -> RunResult:
     return run(spec, backend=backend)
 
 
+def _cpu_only_child() -> None:
+    """Pool initializer: keep a sweep child off the accelerator. A chip
+    belongs to one process, and netsim cells are host numpy by design."""
+    import os
+
+    import jax
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
+
+
 def _run_sweep_process(cells: Sequence[ExperimentSpec], backend,
                        processes: int | None) -> list[RunResult]:
     import multiprocessing as mp
     import os
+    for c in cells:
+        kind = _resolve_backend(c, backend).kind
+        _require(kind == "netsim",
+                 f"parallel='process' runs netsim cells only (host numpy); "
+                 f"cell {c.name!r} is on backend {kind!r}, whose device "
+                 f"belongs to this process -- use parallel='vmap' or serial")
     backend_ser = (backend.to_dict() if isinstance(backend, ComponentSpec)
                    else backend)
     payloads = [(c.to_json(indent=None), backend_ser) for c in cells]
     n_proc = max(1, min(len(cells), processes or os.cpu_count() or 1))
     ctx = mp.get_context("spawn")  # never fork an initialized jax runtime
-    with ctx.Pool(n_proc) as pool:
+    with ctx.Pool(n_proc, initializer=_cpu_only_child) as pool:
         return pool.map(_process_cell, payloads, chunksize=1)

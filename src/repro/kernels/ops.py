@@ -1,15 +1,16 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On this container (CPU) the kernels execute with interpret=True; on real
-TPU hardware set REPRO_PALLAS_INTERPRET=0 (or pass interpret=False) to run
-the compiled Mosaic kernels. `ref.py` holds the pure-jnp oracles used by the
-property tests.
+The platform picks the path: on a TPU the kernels compile to Mosaic
+(`interpret=False`); elsewhere -- the CPU test path -- the gossip ops run
+their jnp references and the other kernels run in the Pallas interpreter.
+An explicit `interpret=` / `use_kernel=` argument overrides the choice
+(the tests use it to check kernel bodies in interpret mode). `ref.py` holds
+the pure-jnp oracles used by the property tests.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -24,26 +25,27 @@ from repro.kernels.selective_scan import selective_scan as _sscan
 from repro.kernels.ssd_scan import ssd_scan as _ssd
 
 
-def _default_interpret() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+def _on_tpu() -> bool:
+    """True when computations run on a TPU, where the kernels compile."""
+    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True,
                     interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = (not _on_tpu()) if interpret is None else interpret
     return _flash(q, k, v, causal=causal, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def selective_scan(x, dt, A, B, C, D_skip, *, interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = (not _on_tpu()) if interpret is None else interpret
     return _sscan(x, dt, A, B, C, D_skip, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ssd_scan(x, dt, A, B, C, *, interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = (not _on_tpu()) if interpret is None else interpret
     return _ssd(x, dt, A, B, C, interpret=interpret)
 
 
@@ -53,7 +55,7 @@ def ssd_scan(x, dt, A, B, C, *, interpret: bool | None = None):
 def gossip_mix(self_buf, neighbor_bufs, self_weight: float,
                edge_weight: float, *, interpret: bool | None = None):
     """Pads the flat buffers to a whole tile count, mixes, and un-pads."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = (not _on_tpu()) if interpret is None else interpret
     (M,) = self_buf.shape
     pad = (-M) % _TILE
     sb = jnp.pad(self_buf, (0, pad))
@@ -77,17 +79,15 @@ def gossip_gather_mix_impl(z, S_in, w_self, w_edge, *, msg=None,
     the dequantized `msg` while the diagonal keeps each node's exact own
     z -- and defaults to z itself (uncompressed).
 
-    Dispatch: on compiled backends (`use_kernel=True`, the default when not
-    interpreting) the gather feeds the Pallas kernel, which makes the k+1
-    AXPYs one VMEM-resident pass. Under `interpret=True` (this CPU
-    container) the Pallas interpreter costs ~ms per grid cell -- two orders
-    off the fused XLA lowering -- so the default routes to the bitwise-
-    equivalent jnp reference, which XLA fuses into a single gather+FMA loop
-    (~6x the dense matmul at n=256, k=4, d=4096; see BENCH_dense.json).
-    Tests pass `use_kernel=True` with `interpret=True` to validate the
-    kernel body itself.
+    Dispatch: on a TPU the gather feeds the compiled Pallas kernel, which
+    makes the k+1 AXPYs one VMEM-resident pass. Elsewhere (the CPU test
+    path) the Pallas interpreter costs ~ms per grid cell -- two orders off
+    the fused XLA lowering -- so the default routes to the jnp reference,
+    which XLA fuses into a single gather+FMA loop. Tests pass
+    `use_kernel=True` with `interpret=True` to validate the kernel body
+    itself.
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = (not _on_tpu()) if interpret is None else interpret
     use_kernel = (not interpret) if use_kernel is None else use_kernel
     if not use_kernel:
         return ref.gossip_gather_mix_ref(z, S_in, w_self, w_edge, msg=msg)
@@ -126,7 +126,7 @@ def compress_mix_impl(z, msg, mask, S_in, w_self, w_edge, *,
     Shapes and the ref/kernel dispatch contract match
     `gossip_gather_mix_impl`; `mask` is 0/1 in z's dtype.
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = (not _on_tpu()) if interpret is None else interpret
     use_kernel = (not interpret) if use_kernel is None else use_kernel
     if not use_kernel:
         return ref.compress_mix_ref(z, msg, mask, S_in, w_self, w_edge)

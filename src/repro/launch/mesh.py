@@ -14,18 +14,18 @@ from __future__ import annotations
 
 import jax
 
-from repro.launch.compat import make_mesh_auto
-
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_auto(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
-    """General mesh for tests/examples (e.g. (2,2,2) on 8 host devices)."""
-    return make_mesh_auto(shape, axes)
+    """Mesh with every axis in Auto mode (e.g. (2,2,2) on 8 host devices
+    for tests/examples)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def mesh_shape(mesh: jax.sharding.Mesh) -> dict[str, int]:

@@ -22,7 +22,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.consensus import tree_mix_collective
 from repro.core.graphs import CommGraph
-from repro.launch.compat import shard_map
 from repro.models import transformer
 from repro.models.common import ModelConfig
 from repro.optim import Optimizer, OptState
@@ -140,9 +139,13 @@ def make_consensus_steps(cfg: ModelConfig, optimizer: Optimizer,
     Pmat = jnp.asarray(graph.mixing_matrix(), jnp.float32)
 
     def _dense_mix(tree):
+        # full float32 products: at the TPU's default precision the weights
+        # and operands would be rounded to bfloat16 at every gossip round
         return jax.tree.map(
             lambda a: jnp.einsum("pq,q...->p...", Pmat,
-                                 a.astype(jnp.float32)).astype(a.dtype),
+                                 a.astype(jnp.float32),
+                                 precision=jax.lax.Precision.HIGHEST
+                                 ).astype(a.dtype),
             tree)
 
     def mix_body(params, opt_state):
@@ -154,10 +157,10 @@ def make_consensus_steps(cfg: ModelConfig, optimizer: Optimizer,
         mixed_z = tree_mix_collective(sq(opt_state.inner["z"]), graph, "pod")
         return params, OptState(opt_state.step, {"z": unsq(mixed_z)})
 
-    mix = shard_map(mix_body, mesh=mesh,
-                    in_specs=(P("pod"), P("pod")),
-                    out_specs=(P("pod"), P("pod")),
-                    axis_names={"pod"}, check_vma=False)
+    mix = jax.shard_map(mix_body, mesh=mesh,
+                        in_specs=(P("pod"), P("pod")),
+                        out_specs=(P("pod"), P("pod")),
+                        axis_names={"pod"}, check_vma=False)
 
     def fused_step(params, opt_state, batch):
         params, opt_state, metrics = local(params, opt_state, batch)

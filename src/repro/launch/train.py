@@ -45,6 +45,30 @@ class TrainReport:
     extras: dict = dataclasses.field(default_factory=dict)
 
 
+def init_pod_state(cfg: ModelConfig, optimizer: Optimizer, mesh,
+                   n_pods: int, seed: int):
+    """Concrete pod-stacked (params, opt_state) -- one independently
+    initialized replica per pod, sharded P('pod', ...) on `mesh` -- plus
+    their shardings `(psh, ssh)`. Call under `shrules.use_rules`."""
+    aparams, pspecs = sp.param_specs(cfg, mesh)
+    astate, sspecs = sp.opt_state_specs(optimizer, aparams, pspecs)
+    aparams, pspecs = sp.pod_stack(aparams, pspecs, n_pods)
+    astate, sspecs = sp.pod_stack(astate, sspecs, n_pods)
+    psh = sp.to_shardings(pspecs, mesh)
+    ssh = sp.to_shardings(sspecs, mesh)
+
+    def init_all(key):
+        def one(k_):
+            prm, _ = transformer.init(k_, cfg)
+            st = optimizer.init(prm)
+            return prm, st
+        return jax.vmap(one)(jax.random.split(key, n_pods))
+
+    params, opt_state = jax.jit(
+        init_all, out_shardings=(psh, ssh))(jax.random.PRNGKey(seed))
+    return params, opt_state, psh, ssh
+
+
 def train_consensus_lm(cfg: ModelConfig, optimizer: Optimizer, mesh,
                        *, steps: int = 100,
                        schedule: CommSchedule | None = None,
@@ -93,24 +117,8 @@ def train_consensus_lm(cfg: ModelConfig, optimizer: Optimizer, mesh,
         mix_target=mix_target)
 
     with shrules.use_rules(shrules.DEFAULT_RULES, mesh):
-        # concrete init, pod-stacked
-        aparams, pspecs = sp.param_specs(cfg, mesh)
-        astate, sspecs = sp.opt_state_specs(optimizer, aparams, pspecs)
-        aparams, pspecs = sp.pod_stack(aparams, pspecs, n_pods)
-        astate, sspecs = sp.pod_stack(astate, sspecs, n_pods)
-        psh = sp.to_shardings(pspecs, mesh)
-        ssh = sp.to_shardings(sspecs, mesh)
-
-        def init_all(key):
-            def one(k_):
-                prm, _ = transformer.init(k_, cfg)
-                st = optimizer.init(prm)
-                return prm, st
-            return jax.vmap(one)(jax.random.split(key, n_pods))
-
-        params, opt_state = jax.jit(
-            init_all, out_shardings=(psh, ssh))(jax.random.PRNGKey(seed))
-
+        params, opt_state, psh, ssh = init_pod_state(cfg, optimizer, mesh,
+                                                     n_pods, seed)
         jit_local = jax.jit(local, in_shardings=(psh, ssh, None),
                             out_shardings=(psh, ssh, None),
                             donate_argnums=(0, 1))

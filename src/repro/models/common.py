@@ -160,32 +160,9 @@ def split_axes(tree: PyTree) -> tuple[PyTree, PyTree]:
 # ---------------------------------------------------------------------------
 
 
-def _barrier_transformable() -> bool:
-    """Older jax (< 0.5) ships no differentiation/batching rules for the
-    optimization_barrier primitive, so any model using it cannot be trained
-    (grad) or pod-vmapped (consensus launcher). Probe trace-only via
-    eval_shape -- no compilation, runs once at import."""
-    try:
-        jax.eval_shape(jax.grad(jax.lax.optimization_barrier), 1.0)
-        jax.eval_shape(jax.vmap(jax.lax.optimization_barrier),
-                       jax.ShapeDtypeStruct((1,), jnp.float32))
-        return True
-    except NotImplementedError:
-        return False
-
-
-if _barrier_transformable():
-    def barrier(x: PyTree) -> PyTree:
-        """Identity that XLA may not optimize across: pins layouts/carry
-        dtypes (see call sites in models/attention.py, transformer.py)."""
-        return jax.lax.optimization_barrier(x)
-else:
-    def barrier(x: PyTree) -> PyTree:
-        """Plain identity fallback: this jax cannot differentiate or batch
-        the barrier primitive. The pinning the barrier provides is a
-        memory/perf optimization, not a correctness requirement, so old
-        environments lose only that."""
-        return x
+#: identity that XLA may not optimize across: pins layouts/carry dtypes (see
+#: call sites in models/attention.py, transformer.py)
+barrier = jax.lax.optimization_barrier
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:

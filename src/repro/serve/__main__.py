@@ -31,11 +31,13 @@ import pathlib
 import sys
 
 from repro.experiments.spec import ExperimentSpec
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve.client import Client, ServeError
 from repro.serve.server import ExperimentServer
 
 
 def _cmd_serve(args) -> int:
+    enable_compile_cache()
     chaos = None
     if args.chaos_plan:
         from repro.serve.chaos import ChaosPlan

@@ -48,6 +48,10 @@ from typing import Any, Callable
 __all__ = ["DeadlineExceeded", "PoolError", "WorkerCrashed", "WorkerPool",
            "execute_requests"]
 
+#: how long the pool's constructor waits for the first worker to import
+#: JAX, reach its devices and report ready
+_READY_TIMEOUT_S = 300.0
+
 
 class PoolError(RuntimeError):
     """Pool-level failure (closed pool, unserviceable job)."""
@@ -145,6 +149,7 @@ def _worker_main(conn, cache_entries: int = 32) -> None:
     """Real worker: owns a private CompileCache, loops on the pipe.
 
     Protocol (tuples over the duplex pipe):
+      <- ("ready", pid, platform, device_count)   (once, at start)
       -> ("run", job_id, [spec_json, ...], [backend_ser, ...])
       <- ("ok", job_id, [result_json, ...], meta) | ("err", job_id, type, msg)
       -> ("ping", token)   <- ("pong", token)
@@ -157,12 +162,17 @@ def _worker_main(conn, cache_entries: int = 32) -> None:
     # the parent owns lifecycle: a terminal Ctrl-C must not race the
     # supervisor's graceful drain
     signal.signal(signal.SIGINT, signal.SIG_IGN)
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    import jax
+
     from repro.experiments.result import RunResult  # noqa: F401 (warm import)
     from repro.experiments.spec import ExperimentSpec
     from repro.serve.cache import CompileCache
 
     cache = CompileCache(max_entries=cache_entries)
-    conn.send(("ready", os.getpid()))
+    devices = jax.devices()
+    conn.send(("ready", os.getpid(), devices[0].platform, len(devices)))
     while True:
         try:
             msg = conn.recv()
@@ -187,9 +197,11 @@ def _worker_main(conn, cache_entries: int = 32) -> None:
             conn.send(("err", job_id, type(e).__name__, str(e)))
 
 
-def _toy_worker_main(conn, cache_entries: int = 32) -> None:
+def _toy_worker_main(conn, cache_entries: int = 32,
+                     platform: tuple[str, int] = ("cpu", 1)) -> None:
     """Test double for the supervisor: interprets each spec_json as a
-    JSON command dict instead of an ExperimentSpec.
+    JSON command dict instead of an ExperimentSpec, and reports
+    `platform` (name, device count) in its ready message.
 
       {"action": "echo", "value": x}       -> result json '{"value": x}'
       {"action": "sleep", "s": 1.0, ...}   -> sleeps, then echoes
@@ -201,7 +213,7 @@ def _toy_worker_main(conn, cache_entries: int = 32) -> None:
     import json
 
     signal.signal(signal.SIGINT, signal.SIG_IGN)
-    conn.send(("ready", os.getpid()))
+    conn.send(("ready", os.getpid(), *platform))
     while True:
         try:
             msg = conn.recv()
@@ -289,6 +301,11 @@ class WorkerPool:
         workers mid-run.
       worker_main: injectable process target (tests use
         `_toy_worker_main`); must be module-level picklable.
+
+    The first worker starts in the constructor and reports its JAX
+    platform. A chip belongs to one process, and a process holds every
+    chip it sees, so on an accelerator the pool refuses (`PoolError`) to
+    start a second worker.
     """
 
     def __init__(self, processes: int, *, cache_entries: int = 32,
@@ -309,6 +326,8 @@ class WorkerPool:
         self._worker_main = worker_main
         self._ctx = multiprocessing.get_context("spawn")
         self._slots = [_Slot(i) for i in range(processes)]
+        self.worker_restarts = 0
+        self.platform, self.device_count = self._start_first()
         self._pending: collections.deque[_Job] = collections.deque()
         self._lock = threading.Lock()
         self._wake_r, self._wake_w = self._ctx.Pipe(duplex=False)
@@ -319,7 +338,6 @@ class WorkerPool:
         self._rr = 0
         self._hb_seq = 0
         # robustness counters (surfaced on server stats / RunMetrics)
-        self.worker_restarts = 0
         self.reenqueues = 0
         self.deadline_missed = 0
         self.jobs_ok = 0
@@ -327,6 +345,34 @@ class WorkerPool:
         self._supervisor = threading.Thread(
             target=self._supervise, name="repro-serve-pool", daemon=True)
         self._supervisor.start()
+
+    def _start_first(self) -> tuple[str, int]:
+        """Spawn slot 0, wait for its ready message, and return the
+        (platform, device count) it reports; on an accelerator, refuse a
+        second worker before it starts."""
+        s = self._slots[0]
+        self._spawn(s)
+        try:
+            if not s.conn.poll(_READY_TIMEOUT_S):
+                raise PoolError(
+                    f"first worker not ready within {_READY_TIMEOUT_S}s")
+            msg = s.conn.recv()
+        except (EOFError, OSError) as e:
+            self._kill_slot(s)
+            raise PoolError("first worker died before it was ready") from e
+        except PoolError:
+            self._kill_slot(s)
+            raise
+        _, _, platform, count = msg
+        s.ready = True
+        if platform != "cpu" and len(self._slots) > 1:
+            self._kill_slot(s)
+            raise PoolError(
+                f"{len(self._slots)} worker processes on {count} {platform} "
+                f"device(s): the first worker holds every chip it sees, so a "
+                f"second one cannot load the accelerator's runtime. Use one "
+                f"worker, or the in-process server (processes=0).")
+        return platform, count
 
     # -- public API ----------------------------------------------------------
 

@@ -266,3 +266,33 @@ def test_run_batch_matches_single_runs():
         assert btr.comms == tr.comms
         assert _rel(btr.fvals, tr.fvals) < 1e-6
         assert _rel(btr.disagreement, tr.disagreement) < 1e-5
+
+
+@pytest.mark.parametrize("schedule", [{"kind": "every"},
+                                      {"kind": "periodic",
+                                       "params": {"h": 4}}])
+def test_compiled_programs_take_problem_data_as_arguments(schedule):
+    """The problem's arrays reach the compiled scan as arguments, not as
+    constants baked into the executable -- neither the subgradient's nor
+    the (jitted) objective's. Baked in, the non-smooth problem's center
+    tensor makes every program as large as its data: a minute of compile
+    per program on a TPU and a cache entry no compile cache admits."""
+    import re
+
+    from repro.experiments import ExperimentSpec
+    from repro.experiments.runner import _dense_parts, _dense_sim
+
+    n, M, d = 16, 5, 256
+    spec = ExperimentSpec(
+        name="hoist", problem={"kind": "nonsmooth",
+                               "params": {"n": n, "M": M, "d": d}},
+        topology={"kind": "expander", "params": {"k": 4, "seed": 0}},
+        schedule=schedule, backends=[{"kind": "dense"}], T=50,
+        eval_every=25, seed=0)
+    sim = _dense_sim(spec, _dense_parts(spec, spec.backends[0]))
+    sim.run(jnp.zeros((n, d)), spec.T, eval_every=spec.eval_every)
+    (exe,) = sim._compiled.values()
+    sizes = [int(np.prod([int(v) for v in dims.split(",")]))
+             for dims in re.findall(r"\[([\d,]+)\][^=\n]*constant\(",
+                                    exe.as_text())]
+    assert max(sizes, default=0) < n * M * 2 * d // 8

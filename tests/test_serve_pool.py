@@ -23,6 +23,7 @@ Client transport units (per-op timeouts, torn-line detection, tolerant
 shutdown) run against tiny hand-rolled socket servers.
 """
 
+import functools
 import json
 import os
 import socket
@@ -81,6 +82,21 @@ def test_toy_pool_echo_roundtrip():
             assert json.loads(payload[0])["value"] == i
             assert meta["reenqueues"] == 0
         assert pool.stats()["jobs_ok"] == 6
+
+
+def test_pool_refuses_more_workers_than_chips():
+    """A worker that reports one TPU chip makes a two-process pool refuse
+    at start -- a second process could not load the chip -- instead of
+    failing jobs or restarting workers; one process per chip is fine."""
+    tpu_worker = functools.partial(_toy_worker_main, platform=("tpu", 1))
+    with pytest.raises(PoolError, match="on 1 tpu device"):
+        WorkerPool(2, worker_main=tpu_worker)
+    with WorkerPool(1, worker_main=tpu_worker) as pool:
+        assert (pool.platform, pool.device_count) == ("tpu", 1)
+        payload, _ = pool.submit([_cmd(action="echo", value=7)],
+                                 [None]).result(timeout=60)
+        assert json.loads(payload[0])["value"] == 7
+        assert pool.stats()["worker_restarts"] == 0
 
 
 @pytest.mark.chaos

@@ -112,6 +112,14 @@ def test_process_sweep_matches_serial_bitwise():
         assert a.extras["sent"] == b.extras["sent"]
 
 
+def test_process_sweep_rejects_device_cells():
+    """Only netsim cells fan out to processes: a dense cell runs on the
+    device, which belongs to this process."""
+    with pytest.raises(ValueError, match="netsim cells only"):
+        run_sweep(_dense_spec(), "seed", [0, 1], parallel="process",
+                  processes=2)
+
+
 def test_run_sweep_rejects_unknown_parallel():
     with pytest.raises(ValueError, match="parallel"):
         run_sweep(_dense_spec(), "seed", [0], parallel="threads")

@@ -118,3 +118,28 @@ def test_adamw_bf16_moments_trains():
         losses.append(float(metrics["loss"]))
     stream.close()
     assert losses[-1] < losses[0]
+
+
+def test_compile_cache_dir_is_fixed_per_checkout(tmp_path, monkeypatch):
+    """The entry points' compile cache: `JAX_COMPILATION_CACHE_DIR` is left
+    to JAX (nothing set in code); otherwise `.jax_cache` at the checkout
+    root, the same path whatever the working directory."""
+    import pathlib
+
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    assert enable_compile_cache() == str(tmp_path / "env")
+    assert jax.config.jax_compilation_cache_dir == was
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    root = pathlib.Path(__file__).resolve().parents[1]
+    try:
+        paths = set()
+        for cwd in (tmp_path, root / "src"):
+            monkeypatch.chdir(cwd)
+            paths.add(enable_compile_cache())
+        assert paths == {str(root / ".jax_cache")}
+        assert jax.config.jax_compilation_cache_dir == str(root / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
