@@ -34,7 +34,8 @@ from repro.core.dda import DDASimulator, stepsize_sqrt
 from repro.core.schedules import EveryIteration
 from repro.experiments import ExperimentSpec, run as run_spec, run_sweep
 from repro.experiments.components import problems, topologies
-from repro.obs import RunMetrics, sample_quantiles, write_json_artifact
+from repro.obs import (RunMetrics, Tracer, sample_quantiles,
+                       write_json_artifact)
 
 SEED_BACKEND = {"kind": "dense", "params": {"mix": "dense",
                                             "loop": "segment"}}
@@ -103,13 +104,15 @@ def bench_path(n: int, d: int, T: int, r: float, k: int, seed: int,
     compile_s = sim.last_timings["compile_s"]  # cold run pays the compile
     walls = []
     for _ in range(repeats):
+        sim.tracer = Tracer()  # the phases of each warm run on its own
         t0 = time.perf_counter()
         trace = sim.run(x0, T, eval_every=eval_every, seed=seed, loop=loop)
         walls.append(time.perf_counter() - t0)
     wall = statistics.median(walls)
-    metrics = RunMetrics(
-        compile_s=compile_s, execute_s=wall,
-        counters={"device_execute_s": sim.last_timings["execute_s"]})
+    # the last warm run's spans: `wait` is the blocked wait for results
+    metrics = RunMetrics(compile_s=compile_s, execute_s=wall,
+                         phases=sim.tracer.phase_totals())
+    sim.tracer = None
     return {"path": label, "n": n, "d": d, "T": T, "k": k,
             "wall_s": round(wall, 4),
             "cold_wall_s": round(cold, 4),

@@ -178,7 +178,7 @@ def dense_phase(n: int = 256, M: int = 30, d: int = 4096, T: int = 300,
         rel = _rel(res.trace.fvals, ref.trace.fvals)
         row = {"compile_s": res.metrics.compile_s,
                "execute_s": res.metrics.execute_s,
-               "device_execute_s": res.metrics.counters["device_execute_s"],
+               "wait_s": res.metrics.phases["wait"]["total_s"],
                "final_F": res.trace.fvals[-1],
                "peak_bytes_in_use": peak,
                "kernel_programs": f"{n_kernel}/{n_prog}",

@@ -30,10 +30,10 @@ Two execution modes:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import time
-from functools import partial
 from typing import Any, Callable, NamedTuple, Sequence
 
 import jax
@@ -192,15 +192,23 @@ class _HoistedProgram:
     its 252 MB center tensor into the program several times over: about a
     minute of compile per program on a TPU host, and a 1.6 GB executable
     that no persistent compile cache of ordinary size admits. Passed as
-    arguments, the program is the same computation on the same values."""
+    arguments, the program is the same computation on the same values.
 
-    def __init__(self, jitfn, args: tuple):
+    `name` names the compiled module (`jit_<name>`) and the root of every
+    op's name stack, so that a device trace says which program ran."""
+
+    def __init__(self, jitfn, args: tuple, name: str):
         traced = jitfn.trace(*args)
         self._consts = list(traced.jaxpr.consts)
         self._out_tree = traced.out_tree
-        run = jax.jit(partial(jax.core.eval_jaxpr, traced.jaxpr.jaxpr))
-        self._exe = run.lower(self._consts,
-                              *jax.tree_util.tree_leaves(args)).compile()
+        jaxpr = traced.jaxpr.jaxpr
+
+        def program(consts, *args):
+            return jax.core.eval_jaxpr(jaxpr, consts, *args)
+
+        program.__name__ = program.__qualname__ = name
+        self._exe = jax.jit(program).lower(
+            self._consts, *jax.tree_util.tree_leaves(args)).compile()
 
     def __call__(self, *args):
         out = self._exe(self._consts, *jax.tree_util.tree_leaves(args))
@@ -211,8 +219,24 @@ class _HoistedProgram:
         return self._exe.as_text()
 
 
+def _program_name(kind: tuple) -> str:
+    """The compiled module's name for a program kind, e.g. ("scan", True)
+    -> `dda_scan_allcomm`, ("vmap", False) -> `dda_vmap_cond`."""
+    name = "dda_" + kind[0]
+    if len(kind) > 1:
+        name += "_allcomm" if kind[1] else "_cond"
+    return name
+
+
 class DDASimulator:
     """Runs DDA with n nodes as a stacked leading axis on one device.
+
+    The traced programs name their layers with `jax.named_scope`s, which
+    change only op metadata and which a device trace keeps per op:
+    `dda.subgrad`, `dda.mix` (holding `dda.compress`, the compressor's
+    own work), `dda.update` (holding `dda.project`) and `dda.evaluate`.
+    Each compiled program is named for its kind (`dda_scan_allcomm`,
+    `dda_scan_cond`, `dda_vmap_*`, `dda_segment`).
 
     Args:
       subgrad_fn: (x_stack[n, ...], t) -> g_stack[n, ...]; node i's
@@ -310,35 +334,38 @@ class DDASimulator:
             compressor is attached ([beyond paper], repro.compress; the
             wire ratio c scales the effective tradeoff r -> r*c)."""
             comp = self.compression
-            if self.mix_mode == "sparse":
+            sparse = self.mix_mode == "sparse"
+            if sparse:
                 from repro.kernels import ops as _kops
+            with jax.named_scope("dda.mix"):
                 if comp is None:
-                    return _kops.gossip_gather_mix_impl(
-                        z, self._S_in, self._w_self, self._w_edge), res
-                corrected = z + res
-                if comp.is_sparsifier:
+                    if sparse:
+                        return _kops.gossip_gather_mix_impl(
+                            z, self._S_in, self._w_self, self._w_edge), res
+                    return _cons.mix_dense(z, self._P), res
+                with jax.named_scope("dda.compress"):
+                    corrected = z + res
+                    if sparse and comp.is_sparsifier:
+                        mask = comp.support_mask_jax(corrected, t)
+                        sent = corrected * mask
+                    else:
+                        sent = comp.compress_jax(corrected, t)
+                    new_res = corrected - sent if comp.error_feedback else res
+                if sparse and comp.is_sparsifier:
                     # fused sparsify-mix: the 0/1 support rides the kernel,
                     # never materializing the masked message stack
-                    mask = comp.support_mask_jax(corrected, t)
                     mixed = _kops.compress_mix_impl(
                         z, corrected, mask, self._S_in, self._w_self,
                         self._w_edge)
-                    sent = corrected * mask
-                else:
-                    sent = comp.compress_jax(corrected, t)
+                elif sparse:
                     mixed = _kops.gossip_gather_mix_impl(
                         z, self._S_in, self._w_self, self._w_edge, msg=sent)
-            else:
-                if comp is None:
-                    return _cons.mix_dense(z, self._P), res
-                corrected = z + res
-                sent = comp.compress_jax(corrected, t)
-                # off-diagonal mixing consumes the TRANSMITTED messages;
-                # the diagonal keeps the node's exact own z
-                mixed = (self._P_diag[:, None] * z
-                         + _cons.mix_dense(sent, self._P_off))
-            new_res = corrected - sent if comp.error_feedback else res
-            return mixed, new_res
+                else:
+                    # off-diagonal mixing consumes the TRANSMITTED messages;
+                    # the diagonal keeps the node's exact own z
+                    mixed = (self._P_diag[:, None] * z
+                             + _cons.mix_dense(sent, self._P_off))
+                return mixed, new_res
 
         def make_body(always_comm: bool):
             """always_comm=True drops the per-iteration `lax.cond`: the
@@ -350,19 +377,22 @@ class DDASimulator:
             def body(carry, inp):
                 z, x, xhat, res, t = carry
                 comm, key = inp
-                g = self.subgrad_fn(x, t, key)
+                with jax.named_scope("dda.subgrad"):
+                    g = self.subgrad_fn(x, t, key)
                 if always_comm:
                     z_mixed, res_new = _mix(z, res, t)
                 else:
                     z_mixed, res_new = jax.lax.cond(
                         comm, _mix, lambda zz, rr, tt: (zz, rr), z, res, t)
-                z_new = z_mixed + g
-                t_new = t + 1.0
-                a_t = self.a_fn(t_new)
-                x_new = -a_t * z_new
-                if self.projection is not None:
-                    x_new = self.projection(x_new)
-                xhat_new = (t * xhat + x_new) / t_new
+                with jax.named_scope("dda.update"):
+                    z_new = z_mixed + g
+                    t_new = t + 1.0
+                    a_t = self.a_fn(t_new)
+                    x_new = -a_t * z_new
+                    if self.projection is not None:
+                        with jax.named_scope("dda.project"):
+                            x_new = self.projection(x_new)
+                    xhat_new = (t * xhat + x_new) / t_new
                 return (z_new, x_new, xhat_new, res_new, t_new), None
             return body
 
@@ -401,13 +431,14 @@ class DDASimulator:
                                             mask.shape[0])
                     carry, _ = jax.lax.scan(seg_body, carry, (mask, keys))
                     z, x, xhat, res, t = carry
-                    fv = jnp.mean(jax.vmap(eval_inline)(xhat))
-                    fvc = eval_inline(jnp.mean(xhat, axis=0))
-                    dis = _cons.disagreement(z)
-                    # mean per-node error-feedback residual norm: the
-                    # compression block's trajectory (zeros uncompressed)
-                    rn = jnp.mean(jnp.sqrt(jnp.sum(
-                        res.reshape(res.shape[0], -1) ** 2, axis=-1)))
+                    with jax.named_scope("dda.evaluate"):
+                        fv = jnp.mean(jax.vmap(eval_inline)(xhat))
+                        fvc = eval_inline(jnp.mean(xhat, axis=0))
+                        dis = _cons.disagreement(z)
+                        # mean per-node error-feedback residual norm: the
+                        # compression block's trajectory (zeros uncompressed)
+                        rn = jnp.mean(jnp.sqrt(jnp.sum(
+                            res.reshape(res.shape[0], -1) ** 2, axis=-1)))
                     return carry, (fv, fvc, dis, rn)
 
                 return jax.lax.scan(seg, state, (masks, starts))
@@ -423,14 +454,18 @@ class DDASimulator:
         # the top of every run/run_batch and read by the experiments runner
         # to populate RunMetrics.compile_s / execute_s.
         self._compiled: dict[tuple, Any] = {}
-        self.last_timings: dict[str, float] = {
-            "compile_s": 0.0, "execute_s": 0.0, "eval_s": 0.0}
+        self.last_timings: dict[str, float] = {"compile_s": 0.0,
+                                               "eval_s": 0.0}
+        #: a `repro.obs.Tracer` that a caller binds for one run (the
+        #: experiments runner does): compiles, program calls and result
+        #: assembly open host spans on it, and so on the profiler's clock
+        #: when one runs. None, the default, traces nothing.
+        self.tracer = None
 
     # -- timed dispatch ------------------------------------------------------
 
     def _reset_timings(self) -> None:
-        self.last_timings = {"compile_s": 0.0, "execute_s": 0.0,
-                             "eval_s": 0.0}
+        self.last_timings = {"compile_s": 0.0, "eval_s": 0.0}
         self.last_res_norms = None
 
     def wire_ratio(self, d: int) -> float:
@@ -439,6 +474,12 @@ class DDASimulator:
         paper's effective tradeoff r -> r*c."""
         return (1.0 if self.compression is None
                 else self.compression.wire_ratio(int(d)))
+
+    def _span(self, name: str):
+        """The bound tracer's host span `name`, or no span when unbound."""
+        if self.tracer is None:
+            return contextlib.nullcontext()
+        return self.tracer.span(name)
 
     def _get_compiled(self, kind: tuple, jitfn, args: tuple):
         """AOT executable for `jitfn` at these argument shapes, or None when
@@ -451,7 +492,8 @@ class DDASimulator:
         `last_timings["compile_s"]` exactly once per shape -- which is what
         makes the cache shareable: a long-lived holder of this simulator
         (the serving layer's compile cache, the adaptive chunk loop) pays
-        compile once and every later dispatch is pure execute."""
+        compile once and every later dispatch is pure execute. A miss is
+        the `compile` span."""
         if not hasattr(jitfn, "trace"):
             return None
         key = kind + tuple((tuple(leaf.shape), str(leaf.dtype))
@@ -459,22 +501,25 @@ class DDASimulator:
         entry = self._compiled.get(key)
         if entry is None:
             t0 = time.perf_counter()
-            entry = _HoistedProgram(jitfn, args)
+            with self._span("compile"):
+                entry = _HoistedProgram(jitfn, args, _program_name(kind))
             self.last_timings["compile_s"] += time.perf_counter() - t0
             self._compiled[key] = entry
         return entry
 
     def _timed_call(self, kind: tuple, jitfn, args: tuple):
         """Dispatch a jitted program through the AOT lower/compile path so
-        compile and execute walls are observable separately (see
-        `_get_compiled`); the execute wall is charged to
-        `last_timings["execute_s"]`."""
+        that compile is observable apart from execution (see
+        `_get_compiled`). The call is the `execute` span: its `dispatch`
+        moves host arguments to the device and returns once the program
+        is queued, its `wait` blocks on the results."""
         entry = self._get_compiled(kind, jitfn, args)
         fn = jitfn if entry is None else entry
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(fn(*args))
-        self.last_timings["execute_s"] += time.perf_counter() - t0
-        return out
+        with self._span("execute"):
+            with self._span("dispatch"):
+                out = fn(*args)
+            with self._span("wait"):
+                return jax.block_until_ready(out)
 
     # -- mix-mode resolution -------------------------------------------------
 
@@ -551,37 +596,45 @@ class DDASimulator:
         if loop != "scan":
             raise ValueError(f"loop must be 'scan' or 'segment', got {loop!r}")
         self._reset_timings()
-        mask_full = np.asarray(self.schedule.comm_mask(0, T), dtype=bool)
-        ac = bool(mask_full.all())
+        # the run's arguments: its comm pattern, initial state and key
+        with self._span("dispatch"):
+            mask_full = np.asarray(self.schedule.comm_mask(0, T),
+                                   dtype=bool)
+            ac = bool(mask_full.all())
+            state = (jnp.zeros_like(x0_stack), x0_stack, x0_stack,
+                     jnp.zeros_like(x0_stack),
+                     jnp.asarray(0.0, jnp.float32))
+            root = jax.random.PRNGKey(seed)
         prog = self._scan_jits[ac]
-        state = (jnp.zeros_like(x0_stack), x0_stack, x0_stack,
-                 jnp.zeros_like(x0_stack), jnp.asarray(0.0, jnp.float32))
-        root = jax.random.PRNGKey(seed)
         S, rem = divmod(T, eval_every)
         outs = []
+        # masks and starts stay on the host: the call moves them
         if S:
-            masks = jnp.asarray(mask_full[:S * eval_every]
-                                .reshape(S, eval_every))
-            starts = jnp.asarray(np.arange(S, dtype=np.int32) * eval_every)
+            masks = mask_full[:S * eval_every].reshape(S, eval_every)
+            starts = np.arange(S, dtype=np.int32) * eval_every
             state, out = self._timed_call(("scan", ac), prog,
                                           (state, masks, starts, root))
             outs.append(out)
         if rem:
-            masks = jnp.asarray(mask_full[S * eval_every:].reshape(1, rem))
-            starts = jnp.asarray(np.array([S * eval_every], dtype=np.int32))
+            masks = mask_full[S * eval_every:].reshape(1, rem)
+            starts = np.array([S * eval_every], dtype=np.int32)
             state, out = self._timed_call(("scan", ac), prog,
                                           (state, masks, starts, root))
             outs.append(out)
         if not outs:  # T == 0: an empty trace, as the legacy loop returns
             return SimTrace([], [], [], [], [])
-        fv, fvc, dis, rn = (np.concatenate([np.asarray(o[i]) for o in outs])
-                            for i in range(4))
-        self.last_res_norms = rn
-        # compressed messages are cheaper on the wire: the time axis charges
-        # the effective tradeoff r*c (c == 1.0 leaves seeds bit-identical)
-        r_eff = self.r * self.wire_ratio(int(np.prod(x0_stack.shape[1:])))
-        return self._assemble_trace(mask_full, T, eval_every, r_eff,
-                                    fv, fvc, dis)
+        with self._span("assemble"):
+            fv, fvc, dis, rn = (np.concatenate([np.asarray(o[i])
+                                                for o in outs])
+                                for i in range(4))
+            self.last_res_norms = rn
+            # compressed messages are cheaper on the wire: the time axis
+            # charges the effective tradeoff r*c (c == 1.0 leaves seeds
+            # bit-identical)
+            r_eff = self.r * self.wire_ratio(
+                int(np.prod(x0_stack.shape[1:])))
+            return self._assemble_trace(mask_full, T, eval_every, r_eff,
+                                        fv, fvc, dis)
 
     def _assemble_trace(self, mask_full, T, eval_every, r,
                         fv, fvc, dis) -> SimTrace:

@@ -245,17 +245,19 @@ def _run_dense(spec: ExperimentSpec, backend: ComponentSpec,
     import jax.numpy as jnp
 
     tr = tracer if tracer is not None else Tracer()
-    with tr.span("build"):
-        parts = _dense_parts(spec, backend)
-        problem, graph = parts["problem"], parts["graph"]
-        schedule, loop = parts["schedule"], parts["loop"]
-        if sim_cache is None:
-            lease = contextlib.nullcontext((_dense_sim(spec, parts), False))
-        else:
-            lease = sim_cache.lease(spec, backend,
-                                    lambda: _dense_sim(spec, parts))
-        x0 = jnp.zeros((problem.n, problem.d))
-    with lease as (sim, cache_hit):
+    with contextlib.ExitStack() as held:
+        with tr.span("build"):
+            parts = _dense_parts(spec, backend)
+            problem, graph = parts["problem"], parts["graph"]
+            schedule, loop = parts["schedule"], parts["loop"]
+            if sim_cache is None:
+                lease = contextlib.nullcontext((_dense_sim(spec, parts),
+                                                False))
+            else:
+                lease = sim_cache.lease(spec, backend,
+                                        lambda: _dense_sim(spec, parts))
+            sim, cache_hit = held.enter_context(lease)
+            x0 = jnp.zeros((problem.n, problem.d))
         if sim_cache is not None:
             # a cached simulator may have been built for a different lane
             # of the same signature: rebind the per-request knobs the
@@ -313,42 +315,43 @@ def _run_dense_leased(spec: ExperimentSpec, backend: ComponentSpec,
                                                  "host"))
     else:
         t0 = time.perf_counter()
-        with profile_ctx(spec.profile_dir):
-            trace = sim.run(x0, spec.T, eval_every=spec.eval_every,
-                            seed=spec.seed, loop=loop)
+        # the simulator opens the compile / execute (dispatch, wait) /
+        # assemble spans of this run on the run's tracer
+        sim.tracer = tr
+        try:
+            with profile_ctx(spec.profile_dir):
+                trace = sim.run(x0, spec.T, eval_every=spec.eval_every,
+                                seed=spec.seed, loop=loop)
+        finally:
+            sim.tracer = None
         wall = time.perf_counter() - t0
-        tr.add_host_span("compile", tr.now() - wall,
-                         sim.last_timings["compile_s"])
-        tr.add_host_span("execute", tr.now() - wall
-                         + sim.last_timings["compile_s"],
-                         wall - sim.last_timings["compile_s"])
         metrics_fields.update(compile_s=sim.last_timings["compile_s"])
         if sim.last_timings["eval_s"]:
             metrics_fields.update(eval_s=sim.last_timings["eval_s"])
-        tr.count("device_execute_s", sim.last_timings["execute_s"])
 
     # execute_s is defined as the non-compile remainder of the backend
     # wall, so compile_s + execute_s == wall_s exactly (JSON back-compat:
-    # wall_s stays the lump sum). Pure device time is the
-    # "device_execute_s" counter.
+    # wall_s stays the lump sum). The blocked wait for the program's
+    # results is the "wait" phase.
     compile_s = float(metrics_fields.get("compile_s", 0.0))
     metrics_fields["execute_s"] = max(wall - compile_s, 0.0)
     metrics_fields["compile_s"] = min(compile_s, wall)
-    eps_value, tta = _target_fields(trace, _eps_value(spec, problem))
-    ratio = sim.wire_ratio(problem.d)
-    predictions = _dense_predictions(graph, spec.r, schedule,
-                                     graph.lambda2(), c=ratio)
-    counts = _dense_message_counts(trace, problem.n, graph.degree,
-                                   problem.d, ratio=ratio)
-    if sim.compression is not None:
-        comp_block = _compression_block(
-            sim.compression.kind, ratio,
-            full_bytes=float(counts["msgs"] * problem.d
-                             * _DENSE_SCALAR_BYTES),
-            wire_bytes=counts["bytes_on_wire"],
-            residual_norms=sim.last_res_norms)
-        extras["compression"] = comp_block
-        metrics_fields["compression"] = comp_block
+    with tr.span("assemble"):
+        eps_value, tta = _target_fields(trace, _eps_value(spec, problem))
+        ratio = sim.wire_ratio(problem.d)
+        predictions = _dense_predictions(graph, spec.r, schedule,
+                                         graph.lambda2(), c=ratio)
+        counts = _dense_message_counts(trace, problem.n, graph.degree,
+                                       problem.d, ratio=ratio)
+        if sim.compression is not None:
+            comp_block = _compression_block(
+                sim.compression.kind, ratio,
+                full_bytes=float(counts["msgs"] * problem.d
+                                 * _DENSE_SCALAR_BYTES),
+                wire_bytes=counts["bytes_on_wire"],
+                residual_norms=sim.last_res_norms)
+            extras["compression"] = comp_block
+            metrics_fields["compression"] = comp_block
     metrics = RunMetrics.from_tracer(tr, **metrics_fields, **counts)
     return RunResult(spec=spec, backend=backend, trace=trace, wall_s=wall,
                      eps_value=eps_value, time_to_target=tta,

@@ -77,4 +77,5 @@ def compress_mix_weighted(self_buf: jax.Array, neighbor_msgs: jax.Array,
         out_specs=pl.BlockSpec((_SUBLANES, _LANES), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, M), self_buf.dtype),
         interpret=interpret,
+        name="compress_mix_weighted",
     )(self_buf, neighbor_msgs, neighbor_masks, ws, we)

@@ -116,4 +116,5 @@ def gossip_mix_weighted(self_buf: jax.Array, neighbor_bufs: jax.Array,
         out_specs=pl.BlockSpec((_SUBLANES, _LANES), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, M), self_buf.dtype),
         interpret=interpret,
+        name="gossip_mix_weighted",
     )(self_buf, neighbor_bufs, ws, we)

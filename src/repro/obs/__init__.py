@@ -27,7 +27,8 @@ measurements. This package is that place, three layers deep:
 
 `obs.profiler.profile_ctx` is the opt-in `jax.profiler` hook
 (`ExperimentSpec.profile_dir`) the dense backend wraps around its scanned
-program.
+program. The tracer's host spans reach such a trace as `repro.*`
+annotations, beside the device ops.
 """
 
 from repro.obs.export import (chrome_trace_events, write_chrome_trace,

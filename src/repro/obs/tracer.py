@@ -4,7 +4,10 @@ Two clocks, never mixed in one event:
 
   * ``"host"`` -- wall-clock seconds from `time.perf_counter()`, relative
     to the tracer's construction time. Used for the coarse phase spans
-    (build / compile / execute / eval) every backend emits.
+    (build / compile / execute / eval) every backend emits. A span opened
+    with `span` is also a `jax.profiler` annotation named
+    ``repro.<name>``, so a profiler trace taken meanwhile holds it on the
+    device ops' clock.
   * ``"sim"`` -- the backend's own simulated-time axis (the netsim event
     clock, or the dense simulator's closed-form `iters*(1/n + k r)`
     charge), in sim units. Used for per-event detail spans (node steps,
@@ -26,8 +29,9 @@ are never dropped.
 from __future__ import annotations
 
 import dataclasses
+import sys
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Iterator, Sequence
 
 __all__ = ["TraceEvent", "Tracer"]
@@ -76,10 +80,14 @@ class Tracer:
 
     @contextmanager
     def span(self, name: str, track: str = "main", **args: Any) -> Iterator[None]:
-        """Host-clock phase span around a `with` block."""
+        """Host-clock phase span around a `with` block. It is also the
+        profiler annotation `repro.<name>`, so that a `jax.profiler` trace
+        taken meanwhile holds the span on its host plane, on the clock of
+        the device ops."""
         t0 = self.now()
         try:
-            yield
+            with _annotation(name):
+                yield
         finally:
             self._emit(TraceEvent(name=name, t0=t0, dur=self.now() - t0,
                                   clock="host", track=track, args=args))
@@ -87,7 +95,8 @@ class Tracer:
     def add_host_span(self, name: str, t0: float, dur: float,
                       track: str = "main", **args: Any) -> None:
         """Record an already-measured host-clock span (seconds, relative to
-        the tracer's origin -- use `now()` to take timestamps)."""
+        the tracer's origin -- use `now()` to take timestamps). Recorded
+        after the fact, it does not reach the profiler."""
         self._emit(TraceEvent(name=name, t0=float(t0), dur=float(dur),
                               clock="host", track=track, args=args))
 
@@ -150,3 +159,13 @@ class Tracer:
             self.events_dropped += 1
             return
         self.events.append(ev)
+
+
+def _annotation(name: str):
+    """`jax.profiler.TraceAnnotation("repro." + name)`, which records
+    only while a profiler runs; no annotation in a process that has not
+    imported JAX, where no profiler can run."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return nullcontext()
+    return jax.profiler.TraceAnnotation("repro." + name)

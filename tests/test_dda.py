@@ -296,3 +296,54 @@ def test_compiled_programs_take_problem_data_as_arguments(schedule):
              for dims in re.findall(r"\[([\d,]+)\][^=\n]*constant\(",
                                     exe.as_text())]
     assert max(sizes, default=0) < n * M * 2 * d // 8
+
+
+_EXPANDER = {"kind": "expander", "params": {"k": 4, "seed": 0}}
+_NONSMOOTH = {"kind": "nonsmooth", "params": {"n": 16, "M": 4, "d": 128}}
+_LAYERS = {"dda.subgrad", "dda.mix", "dda.update", "dda.evaluate"}
+
+
+@pytest.mark.parametrize("case,spec_fields,module,scopes", [
+    ("every", dict(problem=_NONSMOOTH, topology=_EXPANDER,
+                   schedule={"kind": "every"}),
+     "jit_dda_scan_allcomm", _LAYERS),
+    ("periodic", dict(problem=_NONSMOOTH, topology=_EXPANDER,
+                      schedule={"kind": "periodic", "params": {"h": 4}}),
+     "jit_dda_scan_cond", _LAYERS),
+    ("topk", dict(problem=_NONSMOOTH, topology=_EXPANDER,
+                  schedule={"kind": "every"},
+                  compression={"kind": "topk", "params": {"keep": 0.125}}),
+     "jit_dda_scan_allcomm", _LAYERS | {"dda.compress"}),
+    ("projection", dict(problem={"kind": "metric_learning",
+                                 "params": {"n": 3, "m_pairs": 60,
+                                            "d_feat": 6}},
+                        topology={"kind": "complete"},
+                        schedule={"kind": "every"}),
+     "jit_dda_scan_allcomm", _LAYERS | {"dda.project"}),
+])
+def test_compiled_program_names_its_layers(case, spec_fields, module,
+                                           scopes):
+    """The scan program's ops carry the simulator's named scopes in their
+    metadata, which a device trace keeps per op, and its module is named
+    for the program kind."""
+    import re
+
+    from repro.experiments import ExperimentSpec
+    from repro.experiments.runner import _dense_parts, _dense_sim
+
+    spec = ExperimentSpec(name=case, backends=[{"kind": "dense"}], T=20,
+                          eval_every=10, seed=0, **spec_fields)
+    parts = _dense_parts(spec, spec.backends[0])
+    sim = _dense_sim(spec, parts)
+    sim.run(jnp.zeros((parts["problem"].n, parts["problem"].d)), spec.T,
+            eval_every=spec.eval_every)
+    (exe,) = sim._compiled.values()
+    text = exe.as_text()
+    assert text.startswith(f"HloModule {module},")
+    root = f"jit({module.removeprefix('jit_')})/"
+    names = [name for name in re.findall(r'op_name="([^"]*)"', text)
+             if name.startswith(root)]
+    found = {part for name in names for part in name.split("/")
+             if part.startswith("dda.")}
+    assert found == scopes
+    assert "<unknown>" not in text

@@ -199,7 +199,9 @@ def test_dense_run_populates_metrics_and_wall_split():
     assert m.msgs == 40 * 6 * 4            # rounds * n * k
     assert m.bytes_on_wire == m.msgs * 4 * 4.0
     assert {"build"} <= set(m.phases)
-    assert "device_execute_s" in m.counters
+    # the whole run is one program call, and one blocked wait for it
+    assert m.phases["wait"]["count"] == 1
+    assert m.phases["wait"]["total_s"] <= m.execute_s
 
 
 @pytest.mark.parametrize("engine", ["object", "vectorized"])
@@ -329,6 +331,50 @@ def test_dense_profile_dir_captures_a_device_trace(tmp_path):
     run(_tiny_spec(profile_dir=str(tmp_path)))
     files = [p for p in tmp_path.rglob("*") if p.is_file()]
     assert files, "jax.profiler produced no trace files"
+
+
+def test_dense_run_spans_reach_the_profiler(tmp_path):
+    """A dense run under `jax.profiler` leaves its host spans on the
+    trace's host plane as `repro.*` annotations, on the device ops' clock:
+    build, the dispatch of the run's arguments, compile, then per program
+    call an execute holding its dispatch and wait, then assemble."""
+    import jax
+
+    spec = _tiny_spec()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("test.solve"):
+            res = run(spec)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    spans: dict[str, list] = {}
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(("repro.", "test.")):
+                        spans.setdefault(e.name, []).append(
+                            (e.start_ns, e.start_ns + e.duration_ns))
+    for name in ("build", "compile", "execute", "dispatch", "wait",
+                 "assemble"):
+        assert len(spans[f"repro.{name}"]) == \
+            res.metrics.phases[name]["count"], name
+
+    def inside(child, parents):
+        return any(a <= child[0] and child[1] <= b for a, b in parents)
+
+    (solve,) = spans["test.solve"]
+    (build,) = spans["repro.build"]
+    (compile_,) = spans["repro.compile"]  # a fresh simulator compiles
+    for name in ("build", "compile", "execute", "assemble"):
+        assert all(inside(s, [solve]) for s in spans[f"repro.{name}"])
+    first, *calls = sorted(spans["repro.dispatch"])
+    for s in calls + spans["repro.wait"]:
+        assert inside(s, spans["repro.execute"])
+    assert build[1] <= first[0] and first[1] <= compile_[0]
+    assert compile_[1] <= min(spans["repro.execute"])[0]
+    assert max(spans["repro.execute"])[1] <= min(spans["repro.assemble"])[0]
 
 
 # -- CLI ---------------------------------------------------------------------

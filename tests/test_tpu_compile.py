@@ -4,12 +4,15 @@ the compiled program (`tpu_custom_call` in its HLO).
 
 The TPU compiler refuses what interpret mode accepts (unaligned slices,
 too much VMEM, a program too large for the device), so these compiles
-guard the chip path at real widths from the CPU. The topology is described
-inside a module-scoped fixture, never at import: only one process may load
-the TPU library, and under xdist every worker imports this file. The last
-test checks, on the CPU, that the ops pick the jnp reference here and the
-kernel when the platform is a TPU.
+guard the chip path at real widths from the CPU. Each kernel's call
+carries its name, which is how a device trace names it. The topology is
+described inside a module-scoped fixture, never at import: only one
+process may load the TPU library, and under xdist every worker imports
+this file. The last test checks, on the CPU, that the ops pick the jnp
+reference here and the kernel when the platform is a TPU.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -51,12 +54,15 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _assert_kernel(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_kernel(compiled, name="gossip_mix_weighted"):
+    """The compiled program calls the Pallas kernel, under its own name."""
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(rf"%{name}(\.\d+)? = \S+ custom-call\(", text), name
 
 
 def test_gossip_mix_weighted_compiles_for_v5e(one_chip):
-    f = jax.jit(gossip_mix_weighted)
+    f = jax.jit(lambda *a: gossip_mix_weighted(*a))
     _assert_kernel(f.lower(_sds((N, M), jnp.float32, one_chip),
                            _sds((K, N, M), jnp.float32, one_chip),
                            _sds((N,), jnp.float32, one_chip),
@@ -64,12 +70,13 @@ def test_gossip_mix_weighted_compiles_for_v5e(one_chip):
 
 
 def test_compress_mix_weighted_compiles_for_v5e(one_chip):
-    f = jax.jit(compress_mix_weighted)
+    f = jax.jit(lambda *a: compress_mix_weighted(*a))
     _assert_kernel(f.lower(_sds((N, M), jnp.float32, one_chip),
                            _sds((K, N, M), jnp.float32, one_chip),
                            _sds((K, N, M), jnp.float32, one_chip),
                            _sds((N,), jnp.float32, one_chip),
-                           _sds((N, K), jnp.float32, one_chip)).compile())
+                           _sds((N, K), jnp.float32, one_chip)).compile(),
+                   "compress_mix_weighted")
 
 
 def test_dense_scan_program_compiles_for_v5e(one_chip, monkeypatch):
