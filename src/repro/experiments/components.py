@@ -195,15 +195,13 @@ def _nonsmooth_problem(n: int, M: int = 30, d: int = 20,
         return float(np.mean(np.sum(np.max(q, axis=-1), axis=-1)))
 
     import jax.numpy as jnp
+    from repro.kernels import ops as _kops
     centers_j = jnp.asarray(centers)
+    # a second device copy in the Pallas kernel's layout, where it runs
+    centers_k = _kops.nonsmooth_kernel_layout(centers_j)
 
     def subgrad_stack(x_stack, t, key):
-        diff = x_stack[:, None, None, :] - centers_j      # (n, M, 2, d)
-        q = jnp.sum(diff * diff, axis=-1)                 # (n, M, 2)
-        pick = jnp.argmax(q, axis=-1)                     # (n, M)
-        chosen = jnp.take_along_axis(
-            diff, pick[..., None, None], axis=2)[:, :, 0]  # (n, M, d)
-        return 2.0 * jnp.sum(chosen, axis=1)
+        return _kops.nonsmooth_subgrad_impl(x_stack, centers_j, centers_k)
 
     def objective(x):
         diff = x[None, None, None, :] - centers_j

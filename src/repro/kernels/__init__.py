@@ -8,6 +8,8 @@ a pure-jnp oracle in ref.py and a jit'd wrapper in ops.py.
   gossip_mix_weighted -- stacked-node variant with per-edge weight vectors
                          (ops.gossip_gather_mix = gather + this, the dense
                          simulator's k-regular fast path)
+  nonsmooth_subgrad   -- the section V.B problem's subgradient in one pass
+                         over its centres (ops.nonsmooth_subgrad_impl)
 """
 
 from repro.kernels import ops, ref

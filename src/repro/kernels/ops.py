@@ -1,11 +1,12 @@
 """Jit'd public wrappers for the Pallas kernels.
 
 The platform picks the path: on a TPU the kernels compile to Mosaic
-(`interpret=False`); elsewhere -- the CPU test path -- the gossip ops run
-their jnp references and the other kernels run in the Pallas interpreter.
-An explicit `interpret=` / `use_kernel=` argument overrides the choice
-(the tests use it to check kernel bodies in interpret mode). `ref.py` holds
-the pure-jnp oracles used by the property tests.
+(`interpret=False`); elsewhere -- the CPU test path -- the gossip ops and
+the non-smooth subgradient run their jnp references and the other kernels
+run in the Pallas interpreter. An explicit `interpret=` / `use_kernel=`
+argument overrides the choice (the tests use it to check kernel bodies in
+interpret mode). `ref.py` holds the pure-jnp oracles used by the property
+tests.
 """
 
 from __future__ import annotations
@@ -15,6 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import nonsmooth_subgrad as _nsg
 from repro.kernels import ref
 from repro.kernels.compress_mix import compress_mix_weighted as _compress_w
 from repro.kernels.flash_attention import flash_attention as _flash
@@ -152,6 +154,37 @@ def compress_mix_impl(z, msg, mask, S_in, w_self, w_edge, *,
     return out[:n, :M].astype(z.dtype).reshape(z.shape)
 
 
+def nonsmooth_kernel_layout(centers):
+    """The kernel's copy of the (n, M, 2, d) centres
+    (`nonsmooth_subgrad.kernel_layout`) where `nonsmooth_subgrad_impl`
+    calls the kernel -- on a TPU, for d a multiple of 128 -- else None.
+    A problem makes it once, when it is built."""
+    if not (_on_tpu() and _nsg.fits(centers.shape[-1])):
+        return None
+    return _nsg.kernel_layout(centers)
+
+
+def nonsmooth_subgrad_impl(x_stack, centers, centers_k, *,
+                           interpret: bool | None = None,
+                           use_kernel: bool | None = None):
+    """Stacked subgradient of the section V.B non-smooth quadratics:
+    x_stack (n, d), centers (n, M, 2, d), centers_k their kernel layout
+    or None (`nonsmooth_kernel_layout`).
+
+    Dispatch: on a TPU, given the kernel's layout, the Pallas kernel
+    `nonsmooth_subgrad` reads the centres once; elsewhere, and where the
+    shapes do not fit the kernel, the jnp reference runs. Under `vmap`
+    the centres stay unbatched: the kernel's grid gains the lane axis and
+    reads them once per lane. Tests pass `use_kernel=True` with
+    `interpret=True` to check the kernel body."""
+    interpret = (not _on_tpu()) if interpret is None else interpret
+    if use_kernel is None:
+        use_kernel = not interpret and centers_k is not None
+    if not use_kernel:
+        return ref.nonsmooth_subgrad_ref(x_stack, centers)
+    return _nsg.nonsmooth_subgrad(x_stack, centers_k, interpret=interpret)
+
+
 #: jitted front doors; hot loops that are already inside their own jit call
 #: the `_impl` functions directly so the mix inlines into the caller's
 #: program (a nested pjit is a fusion boundary XLA will not cross)
@@ -164,4 +197,5 @@ compress_mix = functools.partial(
 
 __all__ = ["flash_attention", "selective_scan", "ssd_scan", "gossip_mix",
            "gossip_gather_mix", "gossip_gather_mix_impl",
-           "compress_mix", "compress_mix_impl", "ref"]
+           "compress_mix", "compress_mix_impl", "nonsmooth_kernel_layout",
+           "nonsmooth_subgrad_impl", "ref"]

@@ -134,3 +134,17 @@ def compress_mix_ref(z, msg, mask, S_in, w_self, w_edge) -> jax.Array:
             * mask.reshape(n, -1).astype(jnp.float32))
     return gossip_gather_mix_ref(z, S_in, w_self, w_edge,
                                  msg=sent.reshape(z.shape))
+
+
+def nonsmooth_subgrad_ref(x_stack, centers) -> jax.Array:
+    """Stacked subgradient of the section V.B non-smooth quadratics,
+    g_i = 2 sum_j (x_i - c_ij[pick]) with pick the argmax of the two
+    squared distances (ties to piece 0). x_stack: (n, d); centers:
+    (n, M, 2, d). The allclose target for
+    `nonsmooth_subgrad.nonsmooth_subgrad`."""
+    diff = x_stack[:, None, None, :] - centers          # (n, M, 2, d)
+    q = jnp.sum(diff * diff, axis=-1)                   # (n, M, 2)
+    pick = jnp.argmax(q, axis=-1)                       # (n, M)
+    chosen = jnp.take_along_axis(
+        diff, pick[..., None, None], axis=2)[:, :, 0]   # (n, M, d)
+    return 2.0 * jnp.sum(chosen, axis=1)

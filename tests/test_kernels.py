@@ -197,3 +197,105 @@ def test_gossip_gather_mix_weighted_matches_matmul(use_kernel):
     expect = jnp.asarray(W, jnp.float32) @ z
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=1e-5, rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# non-smooth subgradient (paper section V.B): kernel vs the jnp body
+# ---------------------------------------------------------------------------
+
+
+def _nonsmooth(n, M, d, seed=0):
+    rng = np.random.default_rng(seed)
+    offset = rng.normal(0.0, 1.5, (n, 1, 1, d))
+    centers = jnp.asarray(rng.normal(offset, 0.3, (n, M, 2, d)), jnp.float32)
+    x = jnp.asarray(0.9 * offset[:, 0, 0] + rng.normal(0.0, 0.5, (n, d)),
+                    jnp.float32)
+    return x, centers
+
+
+@pytest.mark.parametrize("n,M,d,block", [
+    (8, 3, 128, (8, 1)),      # odd M, one pair a step
+    (16, 5, 256, None),       # block_shape's choice
+    (32, 6, 384, (16, 2)),    # two node blocks, three pair blocks
+    (10, 7, 128, (8, 7)),     # n = 10: a node block with padding rows
+    (16, 30, 128, (8, 15)),   # the benchmark's M, two pair blocks
+])
+def test_nonsmooth_subgrad_kernel_vs_ref(n, M, d, block):
+    """The Pallas kernel (interpret=True) against the jnp body, within
+    float32 rounding of the summed distances."""
+    from repro.kernels import nonsmooth_subgrad as nsg
+    x, centers = _nonsmooth(n, M, d, seed=n + M)
+    out = nsg.nonsmooth_subgrad(x, nsg.kernel_layout(centers), block=block,
+                                interpret=True)
+    expect = ref.nonsmooth_subgrad_ref(x, centers)
+    assert out.shape == (n, d) and out.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                               atol=1e-4, rtol=1e-5)
+
+
+def test_nonsmooth_subgrad_ties_pick_piece_zero():
+    """Where both pieces are equally far (c1 = 2x - c0, exact in small
+    integers) the argmax rule keeps piece 0, in the kernel as in the jnp
+    body: g = 2 sum_j (x - c0), not its negative."""
+    from repro.kernels import nonsmooth_subgrad as nsg
+    n, M, d = 8, 3, 128
+    rng = np.random.default_rng(1)
+    x = rng.integers(-4, 5, (n, d)).astype(np.float32)
+    c0 = rng.integers(-4, 5, (n, M, d)).astype(np.float32)
+    centers = jnp.asarray(np.stack([c0, 2 * x[:, None] - c0], axis=2))
+    want = 2.0 * np.sum(x[:, None] - c0, axis=1)
+    assert np.abs(want).max() > 0
+    expect = ref.nonsmooth_subgrad_ref(jnp.asarray(x), centers)
+    out = nsg.nonsmooth_subgrad(jnp.asarray(x), nsg.kernel_layout(centers),
+                                interpret=True)
+    np.testing.assert_array_equal(np.asarray(expect), want)
+    np.testing.assert_array_equal(np.asarray(out), want)
+
+
+def test_nonsmooth_subgrad_vmap_shares_the_centres():
+    """Lanes of a `vmap` (the batched sweep's) each get their own
+    subgradient, and the kernel's centres operand stays unbatched."""
+    n, M, d, B = 8, 3, 128, 3
+    _, centers = _nonsmooth(n, M, d)
+    from repro.kernels import nonsmooth_subgrad as nsg
+    ck = nsg.kernel_layout(centers)
+    xs = jax.random.normal(jax.random.PRNGKey(3), (B, n, d), jnp.float32)
+
+    def lane(x):
+        return ops.nonsmooth_subgrad_impl(x, centers, ck, interpret=True,
+                                          use_kernel=True)
+
+    out = jax.vmap(lane)(xs)
+    for b in range(B):
+        np.testing.assert_allclose(
+            np.asarray(out[b]),
+            np.asarray(ref.nonsmooth_subgrad_ref(xs[b], centers)),
+            atol=1e-4, rtol=1e-5)
+    calls = [e for e in jax.make_jaxpr(jax.vmap(lane))(xs).jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    assert [v.aval.shape for v in calls[0].invars] == [(B, n, d), ck.shape]
+
+
+@pytest.mark.parametrize("d,on_tpu,kernel", [
+    (256, False, False),   # the CPU: the jnp body
+    (256, True, True),     # a TPU, d a multiple of 128: the kernel
+    (200, True, False),    # a TPU, d not a multiple of 128: the jnp body
+])
+def test_nonsmooth_subgrad_dispatch(monkeypatch, d, on_tpu, kernel):
+    """The platform and the width pick the path; the jnp path is the body
+    itself, bit for bit."""
+    x, centers = _nonsmooth(8, 3, d)
+    monkeypatch.setattr(ops, "_on_tpu", lambda: on_tpu)
+    ck = ops.nonsmooth_kernel_layout(centers)
+    assert (ck is not None) == kernel
+
+    def sub(x):
+        return ops.nonsmooth_subgrad_impl(x, centers, ck)
+
+    jaxpr = str(jax.make_jaxpr(sub)(x))
+    assert ("pallas_call" in jaxpr) == kernel
+    if not on_tpu:
+        np.testing.assert_array_equal(
+            np.asarray(jax.jit(sub)(x)),
+            np.asarray(jax.jit(ref.nonsmooth_subgrad_ref)(x, centers)))

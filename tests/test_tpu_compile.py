@@ -1,6 +1,7 @@
-"""Compile the gossip kernels and the dense scan program for a described
-TPU v5e chip -- no chip attached -- and check that the Pallas kernel is in
-the compiled program (`tpu_custom_call` in its HLO).
+"""Compile the gossip kernels, the non-smooth subgradient kernel and the
+dense scan programs for a described TPU v5e chip -- no chip attached --
+and check that the Pallas kernel is in the compiled program
+(`tpu_custom_call` in its HLO).
 
 The TPU compiler refuses what interpret mode accepts (unaligned slices,
 too much VMEM, a program too large for the device), so these compiles
@@ -102,6 +103,46 @@ def test_dense_scan_program_compiles_for_v5e(one_chip, monkeypatch):
             _sds((2,), jnp.uint32, one_chip))
     for always_comm in (True, False):
         _assert_kernel(sim._scan_jits[always_comm].lower(*args).compile())
+
+
+def test_nonsmooth_subgrad_compiles_for_v5e(one_chip):
+    """The section V.B subgradient kernel at the benchmark's size: n=256
+    nodes, M=30 pairs of centres of width d=4096; and vmapped over two
+    lanes that share the centres, as `run_batch` calls it."""
+    from repro.kernels.nonsmooth_subgrad import nonsmooth_subgrad
+    centers = _sds((30, 2, N, M), jnp.float32, one_chip)
+    f = jax.jit(lambda x, c: nonsmooth_subgrad(x, c))
+    _assert_kernel(f.lower(_sds((N, M), jnp.float32, one_chip), centers)
+                   .compile(), "nonsmooth_subgrad")
+    lanes = jax.jit(jax.vmap(nonsmooth_subgrad, in_axes=(0, None)))
+    _assert_kernel(lanes.lower(_sds((2, N, M), jnp.float32, one_chip),
+                               centers).compile(), "vmap_nonsmooth_subgrad_")
+
+
+def test_nonsmooth_scan_program_compiles_for_v5e(one_chip, monkeypatch):
+    """The whole-run scan of the `nonsmooth` problem (n=8, M=30, d=4096),
+    built and traced with the platform check steered to the TPU: its
+    subgradient is the Pallas kernel, a `tpu_custom_call` in the HLO."""
+    from repro.experiments import runner
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(runner, "_PROBLEM_CACHE", {})
+    n = 8
+    spec = ExperimentSpec(
+        name="tpu_compile",
+        problem={"kind": "nonsmooth",
+                 "params": {"n": n, "M": 30, "d": M, "seed": 0}},
+        topology={"kind": "expander", "params": {"k": K, "seed": 0}},
+        schedule={"kind": "every"}, backends=[{"kind": "dense"}],
+        T=100, eval_every=25, seed=0)
+    sim = runner._dense_sim(spec, runner._dense_parts(spec,
+                                                      spec.backends[0]))
+    state = tuple(_sds((n, M), jnp.float32, one_chip) for _ in range(4)) + (
+        _sds((), jnp.float32, one_chip),)
+    args = (state, _sds((4, 25), jnp.bool_, one_chip),
+            _sds((4,), jnp.int32, one_chip),
+            _sds((2,), jnp.uint32, one_chip))
+    _assert_kernel(sim._scan_jits[True].lower(*args).compile(),
+                   "nonsmooth_subgrad")
 
 
 def test_ops_pick_reference_on_cpu_and_kernel_on_tpu(monkeypatch):
