@@ -15,15 +15,23 @@ compared, each against a limit of its own from `bench/limits/<cell>.json`:
 A gap is max |program - reference| / |reference| over trace points and
 solves; a value that is not finite reads as infinite. A cell compares
 the numbers its limits file names.
+
+Those are the DDA's numbers. A problem module (`bench/problems/<kind>.py`)
+may bring its own: `spec_problem`, `reference_trace`, `readings` and
+`NUMBERS` (`bench/train_ref.py` has them for training); `hooks` decides,
+for the harness and `bench/control.py` alike, which ones a cell uses.
 """
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 import math
+from typing import Callable
 
 import numpy as np
 
-from bench import dda_ref
+from bench import dda_ref, generator
 
 NUMBERS = ("fbar_rel_gap", "fxbar_rel_gap", "disagreement_rel_gap",
            "trace_layout_mismatch")
@@ -31,6 +39,53 @@ NUMBERS = ("fbar_rel_gap", "fxbar_rel_gap", "disagreement_rel_gap",
 #: the program's trace field for each reference series
 _FIELDS = {"fbar": "fvals", "fxbar": "fvals_consensus",
            "disagreement": "disagreement"}
+
+
+@dataclasses.dataclass(frozen=True)
+class Hooks:
+    """What a cell's problem kind supplies to the harness.
+
+    spec_problem: (cfg, seed) -> the `problem` of the solve's spec
+    reference:    (cfg, traffic, seed, dtype, matmul_precision) -> the
+                  reference's series, each a float64 array over trace points
+    readings:     (traces, reference, traffic) -> {number: value}
+    numbers:      the names of the numbers a limits file may hold
+    dda:          the DDA's own hooks, those of a module that brings none
+    """
+
+    spec_problem: Callable
+    reference: Callable
+    readings: Callable
+    numbers: tuple
+    dda: bool
+
+
+def hooks(module) -> Hooks:
+    """The hooks of `module`, a problem module; where it brings none of
+    its own, the DDA's of this file."""
+    own = [name for name in ("spec_problem", "reference_trace", "readings",
+                             "NUMBERS") if hasattr(module, name)]
+    if not own:
+        return Hooks(spec_problem=generator.seeded_problem,
+                     reference=functools.partial(reference_trace, module),
+                     readings=dda_readings, numbers=NUMBERS, dda=True)
+    if len(own) < 4:
+        raise AttributeError(f"{module.__name__} brings {own}: a problem "
+                             f"module brings all four hooks or none")
+    return Hooks(spec_problem=module.spec_problem,
+                 reference=functools.partial(module.reference_trace, module),
+                 readings=module.readings, numbers=tuple(module.NUMBERS),
+                 dda=False)
+
+
+def dda_readings(traces, reference: dict, traffic: dict) -> dict:
+    return readings(traces, reference, traffic["T"], traffic["eval_every"])
+
+
+def series_gaps(series: dict, reference: dict) -> dict:
+    """`<series>_rel_gap` of each series against the reference's."""
+    return {f"{k}_rel_gap": rel_gap(series[k], reference[k])
+            for k in reference}
 
 
 def keep_count(d: int, keep: float) -> int:
@@ -101,14 +156,16 @@ def readings(traces, reference: dict, T: int, eval_every: int) -> dict:
     return out
 
 
-def judge(values: dict, limits: dict) -> tuple[bool, dict]:
+def judge(values: dict, limits: dict,
+          numbers: tuple = NUMBERS) -> tuple[bool, dict]:
     """(correct, {name: {"value", "limit"}}) for the numbers that `limits`
-    names; a cell's limits file leaves out a number that cannot tell a
-    sound run from the control (PERF.md says which and why)."""
-    unknown = set(limits) - set(NUMBERS)
+    names, of the cell's kind's `numbers`; a cell's limits file leaves out
+    a number that cannot tell a sound run from the control (PERF.md says
+    which and why)."""
+    unknown = set(limits) - set(numbers)
     if unknown:
         raise KeyError(f"limits for unknown numbers {sorted(unknown)}")
     checks = {name: {"value": values[name], "limit": limits[name]}
-              for name in NUMBERS if name in limits}
+              for name in numbers if name in limits}
     ok = all(c["value"] <= c["limit"] for c in checks.values())
     return ok, checks

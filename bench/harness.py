@@ -7,7 +7,9 @@ that `BENCHMARK.json` gives it:
 
   bench/configs/<config>.json        sizes, graph, stepsize, precision
   bench/traffic/<traffic>.json       the solve mix (bench/generator.py)
-  bench/problems/<problem kind>.py   plain reference and work counts
+  bench/problems/<problem kind>.py   plain reference and work counts, or
+                                     the plain model of a training cell
+                                     with `bench/train_ref.py`'s hooks
   bench/metrics/<metric>.py          `read(ctx)` of one per-layer metric
   bench/peaks/<device kind>.json     the chip's published peaks
   bench/limits/<cell>.json           the limit of each number compared
@@ -15,7 +17,9 @@ that `BENCHMARK.json` gives it:
 The window drives the serving layer's execution entry,
 `repro.serve.execute_requests`, closed loop: one client, one solve after
 another, through one `CompileCache` warmed in set-up and held for the
-whole window, as the experiment server holds it.
+whole window, as the experiment server holds it. A solve of a `launch`
+configuration is one consensus training run of T steps, and the trace it
+returns holds its losses.
 """
 
 from __future__ import annotations
@@ -156,7 +160,7 @@ class Window:
 
 
 def run_window(cell: Cell, seed: int, seconds: float, ExperimentSpec,
-               cache, execute_requests, annotate) -> Window:
+               cache, execute_requests, annotate, spec_problem) -> Window:
     """Solves back to back until `seconds` have passed; the last solve
     started in time runs to its end and the window closes with it."""
     from bench import generator, trace
@@ -168,7 +172,7 @@ def run_window(cell: Cell, seed: int, seconds: float, ExperimentSpec,
     while time.perf_counter() < deadline:
         index += 1
         spec = ExperimentSpec(**generator.solve_request(
-            cell.cfg, cell.traffic, seed, index))
+            cell.cfg, cell.traffic, seed, index, spec_problem))
         ts = time.perf_counter()
         try:
             with annotate(trace.SOLVE_SPAN):
@@ -201,13 +205,15 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 
     from bench import check, generator
 
-    generator.check_supported(cell.traffic)
+    generator.check_supported(cell.cfg, cell.traffic)
+    kind = check.hooks(load_module(cell.root, "problems",
+                                   cell.cfg["problem"]["kind"]))
     ExperimentSpec, CompileCache, execute_requests = _program()
     cache = CompileCache()
     # set-up: the problem's data, the simulator, its compiled programs
     # (from the persistent cache after a cell's first run), and one solve
-    warm = ExperimentSpec(**generator.solve_request(cell.cfg, cell.traffic,
-                                                    seed, 0))
+    warm = ExperimentSpec(**generator.solve_request(
+        cell.cfg, cell.traffic, seed, 0, kind.spec_problem))
     execute_requests([warm], [None], cache)
     setup_s = time.perf_counter() - t_start
 
@@ -225,7 +231,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
             return contextlib.nullcontext()
     try:
         win = run_window(cell, seed, seconds, ExperimentSpec, cache,
-                         execute_requests, annotate)
+                         execute_requests, annotate, kind.spec_problem)
     finally:
         if trace:
             jax.profiler.stop_trace()
@@ -249,12 +255,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     result["device"] = {**device, "memory_peak_bytes": memory_peak,
                         **dev_extra}
 
-    problem = load_module(cell.root, "problems", cell.cfg["problem"]["kind"])
-    ref = check.reference_trace(problem, cell.cfg, cell.traffic, seed,
-                                "float32", "highest")
-    values = check.readings(win.traces, ref, cell.traffic["T"],
-                            cell.traffic["eval_every"])
-    ok, checks = check.judge(values, cell.limits)
+    ref = kind.reference(cell.cfg, cell.traffic, seed, "float32", "highest")
+    values = kind.readings(win.traces, ref, cell.traffic)
+    ok, checks = check.judge(values, cell.limits, kind.numbers)
     result["correct"] = bool(ok and win.failed == 0 and win.traces)
     result["errors"] = win.errors[:3]
     result["window"] = window_summary(win)

@@ -170,6 +170,26 @@ def test_control_fails(name):
     assert any(gaps[k] > limits[k] for k in gaps), (gaps, limits)
 
 
+def test_control_reads_a_dda_cell(root):
+    """`bench/control.py` reads a DDA cell through the same hooks as the
+    harness: the program's numbers, the control's, and on a circulant
+    graph the gaps of the neighbour-by-neighbour reference."""
+    from bench import control
+    cell = harness.load_cell("nonsmooth.tiny.trace", root)
+    lines = []
+    summary = control.read(cell, [4], lambda text: lines.append(
+        json.loads(text)))
+    line = lines[0]
+    assert lines[-1] == summary and summary["seeds"] == [4]
+    assert set(line["program"]) == set(check.NUMBERS)
+    assert set(line["control"]) == {"fbar_rel_gap", "fxbar_rel_gap",
+                                    "disagreement_rel_gap"}
+    assert line["program"]["fbar_rel_gap"] < 1e-5
+    assert line["control"]["fbar_rel_gap"] > 1e-5
+    assert set(summary["order"]) == set(line["control"])
+    assert line["ref_final_F"] == pytest.approx(line["final_F"], rel=1e-5)
+
+
 # -- faults planted in the timed path must come out as not correct ----------
 
 
