@@ -1,6 +1,10 @@
 """deepseek-v2-236b [moe] -- 60L d_model=5120 128H d_ff(expert)=1536
 vocab=102400; MLA kv_lora=512 q_lora=1536 rope_head=64; MoE 2 shared + 160
-routed top-6; first layer dense (d_ff 12288). [arXiv:2405.04434]"""
+routed top-6, gates left unnormalised and scaled by 16 (norm_topk_prob
+false, routed_scaling_factor 16); first layer dense (d_ff 12288).
+Group-limited routing (the published n_group 8 / topk_group 3, experts
+chosen only within the top 3 of 8 device groups) is not modelled: the
+router takes the greedy top-6 over all 160. [arXiv:2405.04434]"""
 
 from repro.configs.shapes import lm_shapes
 from repro.models.common import ModelConfig
@@ -13,6 +17,7 @@ FULL = ModelConfig(
     num_heads=128, num_kv_heads=128, head_dim=128,
     d_ff=12288, mlp_act="swiglu",
     moe_experts=160, moe_top_k=6, moe_shared=2, moe_d_ff=1536,
+    moe_norm_topk=False, moe_routed_scale=16.0,
     mla_kv_lora=512, mla_q_lora=1536, mla_rope_head_dim=64,
     mla_v_head_dim=128,
     rope_theta=10000.0,

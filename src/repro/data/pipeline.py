@@ -121,9 +121,13 @@ class TokenStream:
         return self
 
     def __next__(self) -> dict:
-        toks = self._q.get()
+        toks = self.next_host()
         return {"tokens": jnp.asarray(toks[:, :-1]),
                 "labels": jnp.asarray(toks[:, 1:])}
+
+    def next_host(self) -> np.ndarray:
+        """The next (batch, seq + 1) block of tokens, on the host."""
+        return self._q.get()
 
     def close(self):
         self._stop.set()

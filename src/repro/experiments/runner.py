@@ -2,7 +2,7 @@
 
 The three front doors this replaces -- `core.dda.DDASimulator` (dense,
 synchronous, one device), `netsim.NetSimulator` (event-driven async
-cluster), `launch.train.train_consensus_lm` (shard_map consensus LM
+cluster), `launch.train.ConsensusProgram` (shard_map consensus LM
 training) -- stay as the engines; this module only WIRES them from an
 `ExperimentSpec`, so benchmarks and examples declare experiments as data
 instead of hand-assembling problems, topologies, schedules and traces per
@@ -19,8 +19,9 @@ Backends (the `backends` registry):
     DenseRTracker).
   * "netsim" -- NetSimulator on a scenario preset (params pick the preset
     and its knobs, plus engine / algorithm / adaptive controller).
-  * "launch" -- train_consensus_lm on a host mesh (params pick mesh shape,
-    optimizer knobs; the problem must be the "lm" kind). `dryrun: true`
+  * "launch" -- a ConsensusProgram on a host mesh (params pick mesh
+    shape, optimizer knobs; the problem must be the "lm" kind), held in
+    the serving layer's compile cache when one is given. `dryrun: true`
     compiles both step programs and runs zero steps.
 """
 
@@ -637,11 +638,17 @@ def _run_netsim(spec: ExperimentSpec, backend: ComponentSpec,
 
 @backends.register("launch")
 def _run_launch(spec: ExperimentSpec, backend: ComponentSpec,
-                tracer: Tracer | None = None) -> RunResult:
+                tracer: Tracer | None = None,
+                program_cache=None) -> RunResult:
+    """Consensus training of the spec's LM problem. `program_cache` (a
+    `repro.serve.CompileCache`) holds the run's `ConsensusProgram` --
+    mesh, jitted init and step programs, their executables -- under the
+    spec's signature, which leaves out the seed: a later request that
+    differs only in seed (or r, name) compiles nothing."""
     import jax
 
     from repro.launch.mesh import make_mesh
-    from repro.launch.train import train_consensus_lm
+    from repro.launch.train import ConsensusProgram
     from repro.models import registry as _models
     from repro.optim import adamw, cosine_lr
 
@@ -651,7 +658,7 @@ def _run_launch(spec: ExperimentSpec, backend: ComponentSpec,
              "launch runs real processes")
     _require(spec.profile_dir is None,
              "profile_dir wraps the dense scanned program; profile the "
-             "launch path with jax.profiler around train_consensus_lm "
+             "launch path with jax.profiler around ConsensusProgram.run "
              "directly")
     params = dict(backend.params)
     mesh_shape = tuple(params.pop("mesh", None) or (1, 1, 1))
@@ -675,6 +682,9 @@ def _run_launch(spec: ExperimentSpec, backend: ComponentSpec,
                  "launch has no F* to target; eps_frac is dense/netsim-only")
         _require(spec.time_limit is None,
                  "time_limit is event-clock only (netsim backends)")
+        _require(spec.compression is None,
+                 "the launch backend gossips uncompressed parameters; "
+                 "compression is dense/netsim-only")
         _require(spec.stepsize == ComponentSpec("sqrt", {"A": 1.0}),
                  "the launch optimizer's LR schedule is the backend's 'lr' "
                  "param; leave spec.stepsize at its default")
@@ -685,22 +695,34 @@ def _run_launch(spec: ExperimentSpec, backend: ComponentSpec,
                 f"devices, have {jax.device_count()} (set "
                 f"XLA_FLAGS=--xla_force_host_platform_device_count=... "
                 f"before any jax import, as launch/dryrun.py does)")
-        mesh = make_mesh(mesh_shape, ("pod", "data", "model"))
         graph = _build_topology(spec, n_pods)
         _require(isinstance(graph, CommGraph),
                  "launch backend needs a fixed CommGraph topology")
         schedule = _build_schedule(spec)
 
-        cfg = _models.get_config(problem.arch, problem.variant)
-        optimizer = adamw(cosine_lr(lr, max(spec.T, 1)))
+        def build_program():
+            cfg = _models.get_config(problem.arch, problem.variant)
+            optimizer = adamw(cosine_lr(lr, max(spec.T, 1)))
+            return ConsensusProgram(
+                cfg, optimizer, make_mesh(mesh_shape, ("pod", "data",
+                                                       "model")),
+                graph, batch_per_node=problem.batch_per_node,
+                seq_len=problem.seq_len, mix_target=mix_target)
+
+    def train(program):
+        if dryrun:
+            return program.dryrun(tr)
+        return program.run(steps=spec.T, schedule=schedule, seed=spec.seed,
+                           r_estimate=spec.r, log_every=log_every, tracer=tr)
+
     t0 = time.perf_counter()
     with tr.span("execute"):
-        report = train_consensus_lm(
-            cfg, optimizer, mesh, steps=spec.T, schedule=schedule,
-            graph=graph, r_estimate=spec.r,
-            batch_per_node=problem.batch_per_node,
-            seq_len=problem.seq_len, seed=spec.seed, log_every=log_every,
-            mix_target=mix_target, dryrun=dryrun, tracer=tr)
+        if program_cache is None:
+            report = train(build_program())
+        else:
+            with program_cache.lease(spec, backend, build_program) as (
+                    program, _):
+                report = train(program)
     wall = time.perf_counter() - t0
 
     # fold the per-step losses into the canonical trace shape at the spec's
@@ -727,6 +749,9 @@ def _run_launch(spec: ExperimentSpec, backend: ComponentSpec,
     # graph neighbors; param_bytes comes measured from the train loop
     compile_s = float(report.extras.get("local_compile_s", 0.0)
                       + report.extras.get("fused_compile_s", 0.0))
+    if "expert_tokens" in report.extras:
+        tr.count("expert_tokens", float(np.sum(report.extras["expert_tokens"])))
+        tr.count("dropped_tokens", float(report.extras["dropped_tokens"]))
     msgs = report.comm_rounds * n_pods * k
     metrics_fields: dict[str, Any] = dict(
         compile_s=min(compile_s, wall),

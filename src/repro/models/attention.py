@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import (ModelConfig, apply_rope, barrier, p, pz,
-                                 rms_norm)
+                                 rms_norm, yarn_freqs, yarn_mscale)
 from repro.runtime.sharding import constrain
 
 PyTree = Any
@@ -71,7 +71,7 @@ _Q_CHUNK = 256
 _KV_CHUNK = 1024
 
 
-def _sdpa_causal_streamed(q, k, v):
+def _sdpa_causal_streamed(q, k, v, scale):
     """Causal attention with the online-softmax (flash) recurrence over KV
     chunks, in plain XLA. q: (B,S,K,G-grouped H,hd); masks use GLOBAL row
     indices so the math is shard-layout independent."""
@@ -80,7 +80,6 @@ def _sdpa_causal_streamed(q, k, v):
     G = H // K
     v_hd = v.shape[-1]
     qg = q.reshape(B, S, K, G, hd)
-    scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
     nc = T // _KV_CHUNK
     ks = jnp.moveaxis(k.reshape(B, nc, _KV_CHUNK, K, hd), 1, 0)
     vs = jnp.moveaxis(v.reshape(B, nc, _KV_CHUNK, K, v_hd), 1, 0)
@@ -111,8 +110,9 @@ def _sdpa_causal_streamed(q, k, v):
     return out.reshape(B, S, H, v_hd)
 
 
-def _sdpa_causal(q, k, v, cfg: ModelConfig):
-    """Grouped causal attention. q: (B,S,H,hd); k,v: (B,T,K,hd).
+def _sdpa_causal(q, k, v, cfg: ModelConfig, scale: float | None = None):
+    """Grouped causal attention. q: (B,S,H,hd); k,v: (B,T,K,hd); the
+    softmax scale defaults to 1/sqrt(hd).
 
     For long sequences the q dimension is processed in chunks under a
     rematerialized scan, so the (S x T) score matrix never materializes --
@@ -122,17 +122,19 @@ def _sdpa_causal(q, k, v, cfg: ModelConfig):
     B, S, H, hd = q.shape
     T, K = k.shape[1], k.shape[2]
     G = H // K
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
     from repro.runtime.sharding import rules_active
     if rules_active() and T > _KV_CHUNK and T % _KV_CHUNK == 0:
         # production path: q rows stay sequence-parallel; stream the softmax
         # over KV chunks (flash recurrence in XLA) so the (S_loc x T) score
         # tensor never materializes. KV-chunking composes with seq_sp
         # sharding (q-chunking would slice the sharded dim).
-        return _sdpa_causal_streamed(q, k, v)
+        return _sdpa_causal_streamed(q, k, v, scale)
     if S <= _CHUNK_THRESHOLD or S % _Q_CHUNK != 0 or rules_active():
         qg = q.reshape(B, S, K, G, hd)
         scores = jnp.einsum("bskgh,btkh->bkgst", qg, k).astype(jnp.float32)
-        scores = scores / jnp.sqrt(hd).astype(jnp.float32)
+        scores = scores * scale
         mask = jnp.tril(jnp.ones((S, T), bool), k=T - S)
         scores = jnp.where(mask, scores, -jnp.inf)
         w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
@@ -143,7 +145,6 @@ def _sdpa_causal(q, k, v, cfg: ModelConfig):
     nc = S // _Q_CHUNK
     qs = jnp.moveaxis(
         q.reshape(B, nc, _Q_CHUNK, K, G, hd), 1, 0)       # (nc,B,c,K,G,hd)
-    scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
     cols = jnp.arange(T)
 
     def chunk_fn(_, inp):
@@ -218,34 +219,58 @@ def gqa_decode(prm, x, cache, cfg: ModelConfig, pos) -> tuple[jax.Array, PyTree]
 
 
 def mla_init(key, cfg: ModelConfig) -> PyTree:
+    """With `mla_q_lora` 0 (DeepSeek-V2-Lite) q is one projection `wq`
+    from the residual, with no low-rank stage."""
     ks = jax.random.split(key, 8)
     D, H = cfg.d_model, cfg.num_heads
     qk_nope, rope_hd = cfg.hd, cfg.mla_rope_head_dim
     v_hd = cfg.mla_v_head_dim or cfg.hd
     kvl, ql = cfg.mla_kv_lora, cfg.mla_q_lora
+    if ql:
+        q = {"wq_a": p(ks[0], (D, ql), ("embed", "q_lora"), cfg.dtype),
+             "q_norm": pz((ql,), ("q_lora",), jnp.float32),
+             "wq_b": p(ks[1], (ql, H, qk_nope + rope_hd),
+                       ("q_lora", "q_heads", "head"), cfg.dtype)}
+    else:
+        q = {"wq": p(ks[0], (D, H, qk_nope + rope_hd),
+                     ("embed", "q_heads", "head"), cfg.dtype)}
     return {
-        "wq_a": p(ks[0], (D, ql), ("embed", "q_lora"), cfg.dtype),
-        "q_norm": pz((ql,), ("q_lora",), jnp.float32),
-        "wq_b": p(ks[1], (ql, H, qk_nope + rope_hd),
-                  ("q_lora", "q_heads", "head"), cfg.dtype),
+        **q,
         "wkv_a": p(ks[2], (D, kvl + rope_hd), ("embed", "kv_lora"), cfg.dtype),
         "kv_norm": pz((kvl,), ("kv_lora",), jnp.float32),
         "wk_b": p(ks[3], (kvl, H, qk_nope), ("kv_lora", "q_heads", "head"),
                   cfg.dtype),
         "wv_b": p(ks[4], (kvl, H, v_hd), ("kv_lora", "q_heads", "head"),
                   cfg.dtype),
-        "wo": p(ks[5], (H, v_hd, D), ("q_heads", "head", "embed"), cfg.dtype),
+        "wo": p(ks[5], (H, v_hd, D), ("q_heads", "head", "embed"), cfg.dtype,
+                scale=(H * v_hd) ** -0.5),
         "norm": pz((D,), ("embed",), jnp.float32),
     }
 
 
+def _mla_rope(x, positions, cfg: ModelConfig):
+    """Rope on the MLA's rope dims, YaRN-scaled when the config is."""
+    return apply_rope(x, positions, cfg.rope_theta,
+                      yarn_freqs(x.shape[-1], cfg))
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(nope + rope dims), times YaRN's mscale(mscale_all_dim)
+    squared, as DeepSeek-V2's attention sets it."""
+    m = yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+    return m * m / float(cfg.hd + cfg.mla_rope_head_dim) ** 0.5
+
+
 def _mla_q(prm, h, cfg: ModelConfig, positions):
-    qk_nope, rope_hd = cfg.hd, cfg.mla_rope_head_dim
-    ql = jnp.einsum("bsd,dq->bsq", h, prm["wq_a"])
-    ql = rms_norm(ql, prm["q_norm"])
-    q = jnp.einsum("bsq,qhk->bshk", ql, prm["wq_b"])
+    qk_nope = cfg.hd
+    if "wq" in prm:
+        q = jnp.einsum("bsd,dhk->bshk", h, prm["wq"])
+    else:
+        ql = jnp.einsum("bsd,dq->bsq", h, prm["wq_a"])
+        ql = rms_norm(ql, prm["q_norm"])
+        q = jnp.einsum("bsq,qhk->bshk", ql, prm["wq_b"])
     q_nope, q_rope = q[..., :qk_nope], q[..., qk_nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = _mla_rope(q_rope, positions, cfg)
     return q_nope, q_rope
 
 
@@ -254,14 +279,15 @@ def _mla_kv_latent(prm, h, cfg: ModelConfig, positions):
     kv = jnp.einsum("bsd,dq->bsq", h, prm["wkv_a"])
     c_kv, k_rope = kv[..., :kvl], kv[..., kvl:]
     c_kv = rms_norm(c_kv, prm["kv_norm"])
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0, :]
+    k_rope = _mla_rope(k_rope[:, :, None, :], positions, cfg)[:, :, 0, :]
     return c_kv, k_rope
 
 
 def mla_apply(prm, x, cfg: ModelConfig, positions) -> jax.Array:
     """Prefill: expand the latent per head, then run the shared (chunked)
     causal attention with the rope dims concatenated onto q/k. The softmax
-    scale uses the combined qk dim (nope+rope), matching DeepSeek-V2."""
+    scale uses the combined qk dim (nope+rope) and YaRN's mscale, matching
+    DeepSeek-V2 (`mla_softmax_scale`)."""
     h = rms_norm(x, prm["norm"])
     q_nope, q_rope = _mla_q(prm, h, cfg, positions)
     c_kv, k_rope = _mla_kv_latent(prm, h, cfg, positions)
@@ -279,7 +305,7 @@ def mla_apply(prm, x, cfg: ModelConfig, positions) -> jax.Array:
     v = barrier(v)
     k_full = constrain(k_full, ("batch", None, "q_heads", "head"))
     v = constrain(v, ("batch", None, "q_heads", "head"))
-    out = _sdpa_causal(q_full, k_full, v, cfg)
+    out = _sdpa_causal(q_full, k_full, v, cfg, mla_softmax_scale(cfg))
     out = jnp.einsum("bshk,hkd->bsd", out, prm["wo"])
     return constrain(out, ("batch", "seq_sp", "embed_act"))
 
@@ -308,7 +334,7 @@ def mla_decode(prm, x, cache, cfg: ModelConfig, pos) -> tuple[jax.Array, PyTree]
     krope = constrain(krope, ("batch", "cache_seq", "head"))
     # absorb W_uk:  (B,1,H,nope) x (kvl,H,nope) -> (B,H,kvl)
     q_abs = jnp.einsum("bshk,qhk->bhq", q_nope, prm["wk_b"])
-    scale = 1.0 / jnp.sqrt(cfg.hd + cfg.mla_rope_head_dim).astype(jnp.float32)
+    scale = mla_softmax_scale(cfg)
     scores = (jnp.einsum("bhq,btq->bht", q_abs, ckv,
                          preferred_element_type=jnp.float32)
               + jnp.einsum("bshk,btk->bht", q_rope, krope,

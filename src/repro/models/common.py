@@ -52,6 +52,15 @@ class ModelConfig:
     head_dim: int = 0                # 0 -> d_model // num_heads
     qkv_bias: bool = False
     rope_theta: float = 500000.0
+    # YaRN rope scaling (the `rope_scaling` block of a published config);
+    # a factor of 1 is plain rope. cos and sin are not scaled: YaRN scales
+    # them by mscale / mscale_all_dim, 1 where the two are equal, as in
+    # every DeepSeek-V2 config
+    rope_factor: float = 1.0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    rope_original_max_positions: int = 4096
     # mlp
     d_ff: int = 0
     mlp_act: str = "swiglu"          # swiglu | squared_relu | gelu
@@ -65,7 +74,18 @@ class ModelConfig:
     moe_top_k: int = 0
     moe_shared: int = 0
     moe_d_ff: int = 0                # expert hidden size (defaults to d_ff)
+    # > 0: experts sharded over the mesh's 'model' axis with a fixed
+    # per-expert capacity (drops overflow); 0: the dropless held-expert
+    # layer (grouped matmul over the tokens routed to the held experts)
     moe_capacity_factor: float = 1.25
+    # the router's width when this config holds only a share of the
+    # experts (0 -> moe_experts, all of them); the share is the contiguous
+    # slice [moe_expert_offset, moe_expert_offset + moe_experts)
+    moe_experts_total: int = 0
+    moe_expert_offset: int = 0
+    moe_norm_topk: bool = True       # renormalise the top-k gates to sum 1
+    moe_routed_scale: float = 1.0    # routed_scaling_factor (unnormalised)
+    moe_seq_aux: float = 0.0         # sequence-wise balance loss alpha
     # mla
     mla_kv_lora: int = 0
     mla_q_lora: int = 0
@@ -94,6 +114,10 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or (self.d_model // max(self.num_heads, 1))
+
+    @property
+    def router_width(self) -> int:
+        return self.moe_experts_total or self.moe_experts
 
     @property
     def num_layers(self) -> int:
@@ -177,10 +201,43 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
                             / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(head_dim: int, cfg: ModelConfig) -> jax.Array:
+    """YaRN (arXiv:2309.00071) rope frequencies: the extrapolated ones
+    (theta^(-2i/d)) for the dimensions that turn more than beta_fast times
+    over the original context, the interpolated ones (divided by the
+    factor) for those that turn fewer than beta_slow times, and a linear
+    blend between, as DeepSeek-V2's `DeepseekV2YarnRotaryEmbedding`."""
+    base = rope_freqs(head_dim, cfg.rope_theta)
+    if cfg.rope_factor <= 1.0:
+        return base
+
+    def dim_of(rotations):
+        return (head_dim * math.log(cfg.rope_original_max_positions
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(cfg.rope_theta)))
+
+    low = max(math.floor(dim_of(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(dim_of(cfg.rope_beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    extrapolate = 1.0 - ramp
+    return base / cfg.rope_factor * ramp + base * extrapolate
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs: jax.Array | None = None) -> jax.Array:
+    """x: (..., seq, heads, head_dim); positions: (..., seq). `freqs`
+    (head_dim/2,) replaces the plain theta^(-2i/d) (YaRN's, `yarn_freqs`)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                        # (hd/2,)
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)                    # (hd/2,)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., s, hd/2)
     angles = angles[..., None, :]                        # (..., s, 1, hd/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)

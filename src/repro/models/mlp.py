@@ -1,13 +1,16 @@
 """Feed-forward blocks: dense (SwiGLU / squared-ReLU / GELU) and
 Mixture-of-Experts with shared experts + top-k token-choice routing.
 
-MoE dispatch uses the sort-based fixed-capacity scheme (no (tokens x experts
-x capacity) one-hot): flatten token assignments, sort by expert id, compute
-each token's slot inside its expert segment, and scatter into an
-(experts, capacity, d) buffer (one overflow row absorbs drops). Experts are
-sharded over the `model` mesh axis (EP); tokens are model-replicated after
-the attention all-reduce, so dispatch/combine stay device-local and the only
-MoE collective is the usual TP reduction of the output.
+The MoE has one router and two dispatches (`moe_apply`). The held-expert
+layer sorts the assignments that land on the experts this config holds
+and runs them as grouped matmuls, dropping nothing. The capacity layer
+uses the sort-based fixed-capacity scheme (no (tokens x experts x
+capacity) one-hot): flatten token assignments, sort by expert id, compute
+each token's slot inside its expert segment, and gather into an
+(experts, capacity, d) buffer (one overflow row absorbs drops). Its experts
+are sharded over the `model` mesh axis (EP); tokens are model-replicated
+after the attention all-reduce, so dispatch/combine stay device-local and
+the only MoE collective is the usual TP reduction of the output.
 """
 
 from __future__ import annotations
@@ -80,24 +83,109 @@ def mlp_apply(prm, x, cfg: ModelConfig, d_ff: int | None = None) -> jax.Array:
 
 
 def moe_init(key, cfg: ModelConfig) -> PyTree:
+    """The held experts (`cfg.moe_experts` of them) and a router over all
+    `cfg.router_width` experts. Each expert's weights are scaled by their
+    own fan-in (D, then F), not by the leading expert dimension."""
     ks = jax.random.split(key, 6)
     D, E = cfg.d_model, cfg.moe_experts
     F = cfg.moe_d_ff or cfg.d_ff
     prm = {
         "norm": pz((D,), ("embed",), jnp.float32),
-        "router": p(ks[0], (D, E), ("embed", "experts"), jnp.float32),
+        "router": p(ks[0], (D, cfg.router_width), ("embed", "experts"),
+                    jnp.float32),
         "w_up": p(ks[1], (E, D, F), ("experts", "embed", "expert_mlp"),
-                  cfg.dtype),
+                  cfg.dtype, scale=D ** -0.5),
         "w_gate": p(ks[2], (E, D, F), ("experts", "embed", "expert_mlp"),
-                    cfg.dtype),
+                    cfg.dtype, scale=D ** -0.5),
         "w_down": p(ks[3], (E, F, D), ("experts", "expert_mlp", "embed"),
-                    cfg.dtype),
+                    cfg.dtype, scale=F ** -0.5),
     }
     if cfg.moe_shared > 0:
         prm["shared"] = mlp_init(ks[4], cfg,
                                  d_ff=(cfg.moe_d_ff or cfg.d_ff) * cfg.moe_shared)
         del prm["shared"]["norm"]  # shares the block norm
     return prm
+
+
+def route(h: jax.Array, router: jax.Array, cfg: ModelConfig):
+    """Token-choice routing over all `router_width` experts. h: (N, D).
+
+    Returns (scores (N, E_all) softmax in fp32, gates (N, K), ids (N, K)):
+    greedy top-k of the scores, renormalised to sum 1 when
+    `cfg.moe_norm_topk`, else scaled by `cfg.moe_routed_scale`
+    (DeepSeek-V2's `norm_topk_prob` / `routed_scaling_factor`). The router
+    product runs at full fp32 precision, as the published gate does."""
+    logits = jnp.einsum("nd,de->ne", h.astype(jnp.float32), router,
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.softmax(logits, axis=-1)
+    gates, ids = jax.lax.top_k(scores, cfg.moe_top_k)
+    if cfg.moe_norm_topk:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    else:
+        gates = gates * cfg.moe_routed_scale
+    return scores, gates, ids
+
+
+def seq_balance_loss(scores: jax.Array, ids: jax.Array, batch: int,
+                     cfg: ModelConfig) -> jax.Array:
+    """DeepSeek-V2's sequence-wise expert-balance loss over all experts:
+    per sequence f_i = E / (K S) * #{t: i in topK(t)} and P_i = mean_t
+    s_{i,t}; alpha * sum_i f_i P_i, averaged over the sequences."""
+    E, K = cfg.router_width, cfg.moe_top_k
+    scores = scores.reshape(batch, -1, E)
+    S = scores.shape[1]
+    picked = jax.nn.one_hot(ids.reshape(batch, S * K), E,
+                            dtype=jnp.float32).sum(axis=1)   # (B, E)
+    f = picked * (E / (K * S))
+    P = scores.mean(axis=1)
+    return cfg.moe_seq_aux * jnp.mean(jnp.sum(f * P, axis=-1))
+
+
+def _held_experts(h, gates, ids, w_up, w_gate, w_down, cfg: ModelConfig):
+    """The held experts' part of the routed output, dropping nothing.
+
+    h: (N, D); gates, ids: (N, K) over all experts. The assignments that
+    land on a held expert (ids in [offset, offset + E)) are sorted by
+    expert, gathered, and run through each expert's SwiGLU as grouped
+    matmuls (`lax.ragged_dot`, which computes only the rows of its
+    groups); the others sort last and contribute zero. Returns the (N, D)
+    fp32 output and the assignments per held expert (E,). The buffer
+    holds all N*K assignments, so none is dropped.
+
+    The rows past the groups are masked to zero at every grouped matmul's
+    input and output: the TPU's grouped matmul leaves them unwritten, in
+    its results and in the gradients of its operands, and unmasked they
+    would reach the tokens' gradients through the gather's transpose."""
+    N, D = h.shape
+    E, K = cfg.moe_experts, cfg.moe_top_k
+    local = ids - cfg.moe_expert_offset
+    held = (local >= 0) & (local < E)
+    key = jnp.where(held, local, E).reshape(N * K)
+    order = jnp.argsort(key, stable=True)
+    group_sizes = jnp.sum(key[:, None] == jnp.arange(E, dtype=key.dtype),
+                          axis=0, dtype=jnp.int32)
+    rows = (jnp.arange(N * K) < jnp.sum(group_sizes))[:, None]
+
+    def grouped(lhs, w):
+        return jnp.where(rows, jax.lax.ragged_dot(lhs, w, group_sizes), 0)
+
+    x = jnp.where(rows, jnp.take(h, order // K, axis=0), 0)  # (N*K, D)
+    up = grouped(x, w_up)
+    gate = grouped(x, w_gate)
+    act = (jax.nn.silu(gate.astype(jnp.float32))
+           * up.astype(jnp.float32)).astype(h.dtype)
+    y = grouped(act, w_down)                                 # sorted order
+    # back to assignment order, one of the k choices at a time (no
+    # (N, K, D) tensor): a gather by the inverse permutation; rows past
+    # the groups are not the held experts' and are masked, not weighted
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(N * K, dtype=order.dtype)).reshape(N, K)
+    out = jnp.zeros((N, D), jnp.float32)
+    for k in range(K):
+        picked = jnp.take(y, inverse[:, k], axis=0).astype(jnp.float32)
+        out = out + jnp.where(held[:, k:k + 1], picked * gates[:, k:k + 1],
+                              0.0)
+    return out, group_sizes
 
 
 def _dispatch_indices(expert_ids: jax.Array, num_experts: int, capacity: int):
@@ -118,10 +206,11 @@ def _dispatch_indices(expert_ids: jax.Array, num_experts: int, capacity: int):
     return dest
 
 
-def _moe_grouped(tokens, router, w_up, w_gate, w_down, cfg: ModelConfig,
+def _moe_grouped(tokens, gates, ids, w_up, w_gate, w_down, cfg: ModelConfig,
                  capacity: int):
-    """Route and run experts for G dispatch groups. tokens: (G, Nl, D),
-    G sharded over 'data', experts over 'model'.
+    """Run experts for G dispatch groups at a fixed capacity. tokens:
+    (G, Nl, D), G sharded over 'data', experts over 'model'; gates, ids:
+    (G, Nl, K). Returns the output and the assignments dropped.
 
     Dispatch is GATHER-based: a cheap per-group 1-D index scatter builds the
     inverse map slot -> source token, then the (G, E, C, D) expert inputs are
@@ -132,9 +221,6 @@ def _moe_grouped(tokens, router, w_up, w_gate, w_down, cfg: ModelConfig,
     G, Nl, D = tokens.shape
     E, K = cfg.moe_experts, cfg.moe_top_k
     C = capacity
-    logits = jnp.einsum("gnd,de->gne", tokens.astype(jnp.float32), router)
-    gates, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)  # (G,Nl,K)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
 
     dest = jax.vmap(
         lambda i: _dispatch_indices(i, E, C))(ids.reshape(G, Nl * K))
@@ -169,46 +255,61 @@ def _moe_grouped(tokens, router, w_up, w_gate, w_down, cfg: ModelConfig,
             flat_out, dest.reshape(G, Nl, K)[:, :, k][..., None], axis=1)
         out = out + picked.astype(jnp.float32) * gates[:, :, k:k + 1]
     out = constrain(out, ("batch", None, "embed_act"))
-    return out.astype(tokens.dtype)
+    return out.astype(tokens.dtype), jnp.sum(dest == E * C, dtype=jnp.int32)
 
 
-def moe_apply(prm, x, cfg: ModelConfig, groups: int = 1) -> jax.Array:
-    """Token-choice top-k MoE with fixed capacity and optional shared experts.
+def moe_apply(prm, x, cfg: ModelConfig, groups: int = 1):
+    """Token-choice top-k MoE with optional shared experts. x: (B,S,D).
 
-    x: (B,S,D). Router in fp32. `groups` partitions the tokens into
-    independent dispatch groups (the launcher sets groups = data-axis size so
-    each data shard routes its own tokens with a LOCAL capacity buffer --
-    dispatch and combine then stay device-local; the only MoE collectives
-    left are the FSDP weight gathers and the TP output reduction).
-    Capacity per group: C = ceil(top_k * tokens_per_group * cf / E).
+    Returns (output, stats): stats holds `aux`, the sequence-wise balance
+    loss (0 unless `cfg.moe_seq_aux`), `expert_tokens`, the assignments
+    routed to each held expert, and `dropped`, the assignments over an
+    expert's capacity (0 on the held-expert layer, which drops none).
+
+    Two dispatches behind one router (`route`):
+      * `moe_capacity_factor` 0: the held-expert layer (`_held_experts`):
+        this config holds experts [offset, offset + moe_experts) of
+        `router_width`, routes every token over all of them and computes
+        its own experts' part, dropping nothing. On one chip that is
+        expert parallelism without its exchange.
+      * `moe_capacity_factor` > 0: every expert held, sharded over the
+        mesh's 'model' axis by GSPMD, each with a fixed capacity per
+        dispatch group, C = ceil(top_k * tokens_per_group * cf / E);
+        overflow drops. `groups` partitions the tokens (the launcher sets
+        the data-axis size) so dispatch and combine stay device-local.
     """
     B, S, D = x.shape
-    E, K = cfg.moe_experts, cfg.moe_top_k
-    h = rms_norm(x, prm["norm"])
+    E = cfg.moe_experts
     N = B * S
-    G = groups if N % groups == 0 else 1
-    Nl = N // G
-    C = max(1, int(-(-K * Nl * cfg.moe_capacity_factor // E)))
-
-    tokens = h.reshape(G, Nl, D)
-    tokens = constrain(tokens, ("batch", None, "embed_act"))
-    combined = _moe_grouped(tokens, prm["router"], prm["w_up"],
-                            prm["w_gate"], prm["w_down"], cfg, C)
-
-    out = combined.reshape(B, S, D)
+    with jax.named_scope("lm.moe.route"):
+        h = rms_norm(x, prm["norm"])
+        scores, gates, ids = route(h.reshape(N, D), prm["router"], cfg)
+        aux = (seq_balance_loss(scores, ids, B, cfg) if cfg.moe_seq_aux
+               else jnp.zeros((), jnp.float32))
+    with jax.named_scope("lm.moe.experts"):
+        if cfg.moe_capacity_factor > 0:
+            if cfg.router_width != E:
+                raise ValueError("a capacity-dispatched MoE holds every "
+                                 "expert; set moe_capacity_factor 0 for a "
+                                 "share of them")
+            G = groups if N % groups == 0 else 1
+            Nl = N // G
+            C = max(1, int(-(-cfg.moe_top_k * Nl * cfg.moe_capacity_factor
+                             // E)))
+            tokens = constrain(h.reshape(G, Nl, D),
+                               ("batch", None, "embed_act"))
+            combined, dropped = _moe_grouped(
+                tokens, gates.reshape(G, Nl, -1), ids.reshape(G, Nl, -1),
+                prm["w_up"], prm["w_gate"], prm["w_down"], cfg, C)
+            counts = jnp.zeros((E,), jnp.int32).at[ids.reshape(-1)].add(1)
+        else:
+            combined, counts = _held_experts(
+                h.reshape(N, D), gates, ids, prm["w_up"], prm["w_gate"],
+                prm["w_down"], cfg)
+            dropped = jnp.zeros((), jnp.int32)
+    out = combined.reshape(B, S, D).astype(x.dtype)
     if "shared" in prm:
-        out = out + _ffn(prm["shared"], h, cfg)
-    return constrain(out, ("batch", "seq", "embed_act"))
-
-
-def moe_aux_loss(prm, x, cfg: ModelConfig) -> jax.Array:
-    """Load-balancing auxiliary loss (Switch-style): E * sum_e f_e * p_e."""
-    B, S, D = x.shape
-    h = rms_norm(x, prm["norm"]).reshape(B * S, D)
-    logits = jnp.einsum("nd,de->ne", h.astype(jnp.float32), prm["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    top1 = jnp.argmax(probs, axis=-1)
-    frac = jnp.mean(jax.nn.one_hot(top1, cfg.moe_experts, dtype=jnp.float32),
-                    axis=0)
-    prob = jnp.mean(probs, axis=0)
-    return cfg.moe_experts * jnp.sum(frac * prob)
+        with jax.named_scope("lm.moe.shared"):
+            out = out + _ffn(prm["shared"], h, cfg)
+    stats = {"aux": aux, "expert_tokens": counts, "dropped": dropped}
+    return constrain(out, ("batch", "seq", "embed_act")), stats

@@ -3,7 +3,9 @@
 Full production configs live in `repro/configs/<id>.py` (one file per
 assigned architecture, exact published hyperparameters). Each config module
 exposes `FULL` (the published config), `SMOKE` (a reduced same-family config
-for CPU tests) and `SHAPES` (the input-shape set assigned to the arch).
+for CPU tests) and `SHAPES` (the input-shape set assigned to the arch); a
+module may add `VARIANTS`, further configs by name (e.g. one chip's share
+of an expert-parallel layer).
 """
 
 from __future__ import annotations
@@ -22,6 +24,7 @@ ARCH_IDS = (
     "zamba2-2.7b",
     "falcon-mamba-7b",
     "llama-3.2-vision-90b",
+    "deepseek-v2-lite",
 )
 
 
@@ -34,7 +37,12 @@ def get_config(arch_id: str, variant: str = "full"):
     if arch_id not in ARCH_IDS:
         raise ValueError(f"unknown arch {arch_id!r}; have {ARCH_IDS}")
     mod = _module(arch_id)
-    return mod.FULL if variant == "full" else mod.SMOKE
+    named = {"full": mod.FULL, "smoke": mod.SMOKE,
+             **getattr(mod, "VARIANTS", {})}
+    if variant not in named:
+        raise ValueError(f"{arch_id} has no variant {variant!r}; have "
+                         f"{sorted(named)}")
+    return named[variant]
 
 
 def get_shapes(arch_id: str) -> dict[str, Any]:

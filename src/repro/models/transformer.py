@@ -86,21 +86,36 @@ def _mixer_apply(kind: str, prm, x, cfg, positions, shared, enc):
     raise ValueError(kind)
 
 
+#: the named scope of each block kind's mixer in the device trace
+_MIXER_SCOPE = {"attn": "lm.attn", "attn_moe": "lm.attn", "mla": "lm.mla",
+                "mla_moe": "lm.mla", "cross_attn": "lm.cross_attn",
+                "mamba1": "lm.ssm", "mamba2": "lm.ssm",
+                "shared_attn": "lm.attn"}
+
+
 def _block_apply(kind: str, prm, x, cfg: ModelConfig, positions, shared, enc,
                  moe_groups: int):
-    x = x + _mixer_apply(kind, prm, x, cfg, positions, shared, enc)
+    """One block; returns (x, the MoE's stats or None)."""
+    stats = None
+    with jax.named_scope(_MIXER_SCOPE[kind]):
+        x = x + _mixer_apply(kind, prm, x, cfg, positions, shared, enc)
     if kind.endswith("_moe"):
-        x = x + mlp_mod.moe_apply(prm["moe"], x, cfg, groups=moe_groups)
+        with jax.named_scope("lm.moe"):
+            out, stats = mlp_mod.moe_apply(prm["moe"], x, cfg,
+                                           groups=moe_groups)
+        x = x + out
     elif kind in ("attn", "mla", "cross_attn"):
-        x = x + mlp_mod.mlp_apply(prm["mlp"], x, cfg)
+        with jax.named_scope("lm.mlp"):
+            x = x + mlp_mod.mlp_apply(prm["mlp"], x, cfg)
     elif kind == "shared_attn" and shared.get("mlp") is not None:
-        x = x + mlp_mod.mlp_apply(shared["mlp"], x, cfg)
+        with jax.named_scope("lm.mlp"):
+            x = x + mlp_mod.mlp_apply(shared["mlp"], x, cfg)
     # mamba1/mamba2 blocks are mixer-only (falcon-mamba has d_ff=0);
     # zamba2's shared block carries the model's single (shared) FFN.
     # The residual stream BETWEEN blocks is sequence-parallel (seq_sp ->
     # model, Megatron SP): it is what the scan checkpoints, so this
     # constraint sets the saved-activation footprint.
-    return constrain(x, ("batch", "seq_sp", "embed_act"))
+    return constrain(x, ("batch", "seq_sp", "embed_act")), stats
 
 
 def _shared_attn_apply(lora, shared, x, cfg: ModelConfig, positions):
@@ -158,7 +173,7 @@ def _block_decode(kind: str, prm, x, cache, cfg: ModelConfig, pos, shared,
         raise ValueError(kind)
     x = x + out.astype(x.dtype)  # cache dtype must not promote the carry
     if kind.endswith("_moe"):
-        x = x + mlp_mod.moe_apply(prm["moe"], x, cfg, groups=moe_groups)
+        x = x + mlp_mod.moe_apply(prm["moe"], x, cfg, groups=moe_groups)[0]
     elif kind in ("attn", "mla", "cross_attn"):
         x = x + mlp_mod.mlp_apply(prm["mlp"], x, cfg)
     elif kind == "shared_attn" and shared.get("mlp") is not None:
@@ -257,36 +272,65 @@ def _unembed(params, x, cfg: ModelConfig):
     return constrain(logits, ("batch", "seq", "vocab"))
 
 
+def _moe_stats(prologue: list, stacked: list, cfg: ModelConfig) -> dict:
+    """The MoE layers' stats in layer order: `aux` summed over them,
+    `expert_tokens` (moe layers, held experts), `dropped` summed."""
+    E = cfg.moe_experts
+    per_layer = [s["expert_tokens"][None] for s in prologue]
+    if stacked:  # (n_super, E) per MoE slot -> superblock-major layer order
+        per_layer.append(jnp.stack([s["expert_tokens"] for s in stacked],
+                                   axis=1).reshape(-1, E))
+    every = prologue + stacked
+    return {
+        "aux": sum((jnp.sum(s["aux"]) for s in every),
+                   jnp.zeros((), jnp.float32)),
+        "expert_tokens": (jnp.concatenate(per_layer) if per_layer
+                          else jnp.zeros((0, E), jnp.int32)),
+        "dropped": sum((jnp.sum(s["dropped"]) for s in every),
+                       jnp.zeros((), jnp.int32)),
+    }
+
+
 def forward(params, tokens, cfg: ModelConfig, enc: jax.Array | None = None,
-            moe_groups: int = 1) -> jax.Array:
+            moe_groups: int = 1, with_stats: bool = False):
     """Training/prefill forward -> logits (B,S,V). `enc`: (B,N,E) stubbed
-    encoder states for VLM cross-attention (precomputed patch embeddings)."""
+    encoder states for VLM cross-attention (precomputed patch embeddings).
+    `with_stats` returns (logits, the MoE layers' stats, `_moe_stats`)."""
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     x = _embed(params, tokens, cfg)
 
     shared = {"attn": params.get("shared_attn"),
               "mlp": params.get("shared_mlp")}
+    prologue_stats = []
     for i, kind in enumerate(cfg.prologue):
-        x = _block_apply(kind, params["prologue"][i], x, cfg, positions,
-                         shared, enc, moe_groups)
+        x, st = _block_apply(kind, params["prologue"][i], x, cfg, positions,
+                             shared, enc, moe_groups)
+        if st is not None:
+            prologue_stats.append(st)
 
     def superblock(x, slot_params):
         # The barrier pins the saved scan carry to bf16: without it XLA
         # hoists the rms_norm upcast through the carry history buffer and
         # stores the full (L, B, S, D) residual stack in f32 (2x memory).
         x = barrier(x)
+        stats = []
         for i, kind in enumerate(cfg.superblock):
-            x = _block_apply(kind, slot_params[f"slot{i}"], x, cfg, positions,
-                             shared, enc, moe_groups)
-        return x, None
+            x, st = _block_apply(kind, slot_params[f"slot{i}"], x, cfg,
+                                 positions, shared, enc, moe_groups)
+            if st is not None:
+                stats.append(st)
+        return x, stats
 
     body = superblock
     if cfg.remat:
         body = jax.checkpoint(
             superblock, policy=jax.checkpoint_policies.nothing_saveable)
-    x, _ = jax.lax.scan(body, x, params["stack"])
-    return _unembed(params, x, cfg)
+    x, stack_stats = jax.lax.scan(body, x, params["stack"])
+    logits = _unembed(params, x, cfg)
+    if not with_stats:
+        return logits
+    return logits, _moe_stats(prologue_stats, stack_stats, cfg)
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
@@ -371,7 +415,13 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig,
     return logits, new_cache
 
 
+def loss_and_stats(params, batch, cfg: ModelConfig, moe_groups: int = 1):
+    """(cross-entropy plus the MoE layers' balance losses, their stats)."""
+    logits, stats = forward(params, batch["tokens"], cfg,
+                            enc=batch.get("enc"), moe_groups=moe_groups,
+                            with_stats=True)
+    return cross_entropy_loss(logits, batch["labels"]) + stats["aux"], stats
+
+
 def loss_fn(params, batch, cfg: ModelConfig, moe_groups: int = 1) -> jax.Array:
-    logits = forward(params, batch["tokens"], cfg, enc=batch.get("enc"),
-                     moe_groups=moe_groups)
-    return cross_entropy_loss(logits, batch["labels"])
+    return loss_and_stats(params, batch, cfg, moe_groups)[0]

@@ -1,4 +1,6 @@
-"""Compile cache: warm `DDASimulator` instances keyed by program shape.
+"""Compile cache: warm `DDASimulator` instances keyed by program shape,
+and, for the launch backend, warm `ConsensusProgram`s (its mesh, jitted
+init and step programs and their executables) under the same signature.
 
 The cost structure the server amortizes is XLA compilation: a cold
 `repro.run()` on the dense backend traces + lowers + compiles the scanned

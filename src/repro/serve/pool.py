@@ -89,13 +89,15 @@ def execute_requests(specs: list, backends: list, cache) -> tuple[list, dict]:
     from repro.experiments.runner import (_build_schedule,
                                           _dense_batch_results, _dense_parts,
                                           _dense_sim, _resolve_backend,
-                                          _run_dense)
+                                          _run_dense, _run_launch)
     from repro.experiments.runner import run as _run
 
     if len(specs) == 1:
         backend = _resolve_backend(specs[0], backends[0])
         if backend.kind == "dense":
             return [_run_dense(specs[0], backend, sim_cache=cache)], {}
+        if backend.kind == "launch":
+            return [_run_launch(specs[0], backend, program_cache=cache)], {}
         return [_run(specs[0], backend=backend)], {}
 
     import jax.numpy as jnp

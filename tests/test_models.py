@@ -135,7 +135,8 @@ def test_param_counts_match_published():
     expected = {"llama3-8b": 8.0e9, "qwen1.5-110b": 111e9,
                 "deepseek-v2-236b": 236e9,
                 "llama4-maverick-400b-a17b": 400e9,
-                "falcon-mamba-7b": 7.3e9}
+                "falcon-mamba-7b": 7.3e9,
+                "deepseek-v2-lite": 15.7e9}
     for arch, want in expected.items():
         cfg = get_config(arch, "full")
         box = []

@@ -30,13 +30,13 @@ N, M, K = 256, 4096, 4
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     import os
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
+        described = topologies.get_topology_desc(platform="tpu",
+                                                 topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler, or its library is held
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     # a program compiled for a described chip is written to the persistent
@@ -46,9 +46,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield described
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _sds(shape, dtype, sharding):
@@ -143,6 +148,53 @@ def test_nonsmooth_scan_program_compiles_for_v5e(one_chip, monkeypatch):
             _sds((2,), jnp.uint32, one_chip))
     _assert_kernel(sim._scan_jits[True].lower(*args).compile(),
                    "nonsmooth_subgrad")
+
+
+#: a v5e chip's HBM, 16 GiB
+V5E_HBM = 16 * 2 ** 30
+
+
+@pytest.mark.parametrize("step", ["local", "fused"])
+def test_deepseek_v2_lite_share_steps_compile_for_v5e_2x2(topo, step):
+    """The `launch.consensus4` cell's step programs at the real size:
+    DeepSeek-V2-Lite's EP-8 share (1 dense + 5 MoE layers, 8 of 64
+    experts, 1/8 of the vocabulary), one replica per chip of a described
+    v5e:2x2, 4 x 4096 tokens a replica. The TPU compiler refuses a
+    program that overflows a chip's HBM; its own peak (the donated state
+    and the temporaries live at once, 12.6-12.7 GiB) stays under the
+    chip's 16 GiB. The sum of arguments and temporaries overstates it by
+    3 GB, as they do not all live at once. At 8 sequences a replica the
+    compiler refuses the step (16.06 of 15.75 GiB). The held experts'
+    grouped matmuls and, in the fused step, the gossip's all-gathers are
+    in the program."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core.graphs import build_graph
+    from repro.launch.train import ConsensusProgram
+    from repro.models import registry
+    from repro.optim import adamw, cosine_lr
+    cfg = registry.get_config("deepseek-v2-lite", "ep8")
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1, 1),
+                ("pod", "data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 3)
+    program = ConsensusProgram(cfg, adamw(cosine_lr(4.2e-4, 8)), mesh,
+                               build_graph("complete", 4),
+                               batch_per_node=4, seq_len=4096)
+    shapes = jax.eval_shape(program._jits["init"], jax.random.PRNGKey(0))
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        shapes, program.state_shardings)
+    rows = NamedSharding(mesh, P("pod"))
+    batch = {k: _sds((4, 4, 4096), jnp.int32, rows)
+             for k in ("tokens", "labels")}
+    with program._rules():
+        compiled = program._jits[step].lower(*state, batch).compile()
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes < m.peak_memory_in_bytes < V5E_HBM, \
+        m.peak_memory_in_bytes
+    text = compiled.as_text()
+    assert "ragged-dot-metadata" in text
+    assert ("all-gather" in text) == (step == "fused")
 
 
 def test_ops_pick_reference_on_cpu_and_kernel_on_tpu(monkeypatch):
