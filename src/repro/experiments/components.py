@@ -199,14 +199,18 @@ def _nonsmooth_problem(n: int, M: int = 30, d: int = 20,
     centers_j = jnp.asarray(centers)
     # a second device copy in the Pallas kernel's layout, where it runs
     centers_k = _kops.nonsmooth_kernel_layout(centers_j)
+    # the objective's split distances read the centres as one (2nM, d)
+    # matrix, kept as such: a TPU tiles the (n, M, 2, d) array by its pair
+    # axis, and a matmul over it would copy it into rows first
+    centers_sq = jnp.sum(centers_j * centers_j, axis=-1)
+    centers_rows = centers_j.reshape(n * M * 2, d)
 
     def subgrad_stack(x_stack, t, key):
-        return _kops.nonsmooth_subgrad_impl(x_stack, centers_j, centers_k)
+        return _kops.nonsmooth_subgrad_impl(
+            x_stack, centers_rows.reshape(n, M, 2, d), centers_k)
 
     def objective(x):
-        diff = x[None, None, None, :] - centers_j
-        q = jnp.sum(diff * diff, axis=-1)
-        return jnp.mean(jnp.sum(jnp.max(q, axis=-1), axis=-1))
+        return _kops.nonsmooth_objective(x, centers_rows, centers_sq)
 
     return Problem(name="nonsmooth", n=n, d=d, grad_fn=grad_fn,
                    eval_fn=eval_fn, subgrad_stack=subgrad_stack,

@@ -6,7 +6,8 @@ the non-smooth subgradient run their jnp references and the other kernels
 run in the Pallas interpreter. An explicit `interpret=` / `use_kernel=`
 argument overrides the choice (the tests use it to check kernel bodies in
 interpret mode). `ref.py` holds the pure-jnp oracles used by the property
-tests.
+tests. Beside the subgradient's dispatch sits the same problem's
+objective, `nonsmooth_objective`, one matmul with no kernel of its own.
 """
 
 from __future__ import annotations
@@ -185,6 +186,24 @@ def nonsmooth_subgrad_impl(x_stack, centers, centers_k, *,
     return _nsg.nonsmooth_subgrad(x_stack, centers_k, interpret=interpret)
 
 
+def nonsmooth_objective(x, centers_rows, centers_sq):
+    """F(x) of the section V.B non-smooth quadratics, x (d,): the (n, M,
+    2, d) centres given as their (2nM, d) matrix of rows, `centers_rows`,
+    and their squared norms (n, M, 2), `centers_sq`, both made once with
+    the problem. Each squared distance splits as ||x||^2 - 2<x, c> +
+    ||c||^2, so F(x) = M ||x||^2 + mean_i sum_j max_k (||c_ijk||^2 -
+    2<x, c_ijk>): the cross terms are one matvec against the rows, and
+    under `vmap` over x one matmul on the MXU. The max keeps the farther
+    centre of each pair, so the split cancels little; where F is far
+    below M ||x||^2 it loses digits in that ratio. The product is taken at
+    "highest": the TPU's default runs a float32 dot as one bfloat16 pass.
+    `ref.nonsmooth_objective_ref` is the direct-difference form."""
+    M = centers_sq.shape[1]
+    cross = jnp.dot(centers_rows, x, precision=jax.lax.Precision.HIGHEST)
+    far = jnp.max(centers_sq - 2.0 * cross.reshape(centers_sq.shape), axis=-1)
+    return M * jnp.sum(x * x) + jnp.mean(jnp.sum(far, axis=-1))
+
+
 #: jitted front doors; hot loops that are already inside their own jit call
 #: the `_impl` functions directly so the mix inlines into the caller's
 #: program (a nested pjit is a fusion boundary XLA will not cross)
@@ -198,4 +217,4 @@ compress_mix = functools.partial(
 __all__ = ["flash_attention", "selective_scan", "ssd_scan", "gossip_mix",
            "gossip_gather_mix", "gossip_gather_mix_impl",
            "compress_mix", "compress_mix_impl", "nonsmooth_kernel_layout",
-           "nonsmooth_subgrad_impl", "ref"]
+           "nonsmooth_subgrad_impl", "nonsmooth_objective", "ref"]

@@ -1,5 +1,6 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose targets in
-tests/test_kernels.py). Deliberately naive and readable."""
+"""Pure-jnp oracles for every Pallas kernel and for
+`ops.nonsmooth_objective` (the allclose targets in tests/test_kernels.py).
+Deliberately naive and readable."""
 
 from __future__ import annotations
 
@@ -148,3 +149,13 @@ def nonsmooth_subgrad_ref(x_stack, centers) -> jax.Array:
     chosen = jnp.take_along_axis(
         diff, pick[..., None, None], axis=2)[:, :, 0]   # (n, M, d)
     return 2.0 * jnp.sum(chosen, axis=1)
+
+
+def nonsmooth_objective_ref(x, centers) -> jax.Array:
+    """F(x) of the section V.B non-smooth quadratics, the mean over nodes
+    of sum_j max(||x - c_ij0||^2, ||x - c_ij1||^2), by direct differences.
+    x: (d,); centers: (n, M, 2, d). The allclose target for
+    `ops.nonsmooth_objective`."""
+    diff = x[None, None, None, :] - centers
+    q = jnp.sum(diff * diff, axis=-1)
+    return jnp.mean(jnp.sum(jnp.max(q, axis=-1), axis=-1))

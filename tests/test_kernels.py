@@ -299,3 +299,111 @@ def test_nonsmooth_subgrad_dispatch(monkeypatch, d, on_tpu, kernel):
         np.testing.assert_array_equal(
             np.asarray(jax.jit(sub)(x)),
             np.asarray(jax.jit(ref.nonsmooth_subgrad_ref)(x, centers)))
+
+
+# ---------------------------------------------------------------------------
+# non-smooth objective (paper section V.B): split distances vs direct ones
+# ---------------------------------------------------------------------------
+
+
+def _objective_f64(x, centers):
+    """F(x) in float64 numpy, the third opinion on both float32 forms."""
+    diff = (np.asarray(x, np.float64)[None, None, None]
+            - np.asarray(centers, np.float64))
+    return float(np.mean(np.sum(np.max(np.sum(diff * diff, -1), -1), -1)))
+
+
+def _split_objective(centers):
+    rows = centers.reshape(-1, centers.shape[-1])
+    centers_sq = jnp.sum(centers * centers, axis=-1)
+    return jax.jit(lambda x: ops.nonsmooth_objective(x, rows, centers_sq))
+
+
+def _assert_objective(got, x, centers, rtol=1e-6):
+    """Within `rtol` of the direct-difference form and of float64."""
+    want = _objective_f64(x, centers)
+    direct = float(ref.nonsmooth_objective_ref(jnp.asarray(x), centers))
+    assert abs(float(got) - direct) <= rtol * abs(direct)
+    assert abs(float(got) - want) <= rtol * abs(want)
+
+
+@pytest.mark.parametrize("n,M,d", [
+    (1, 3, 128),      # one node
+    (4, 5, 200),      # d not a multiple of 128
+    (8, 30, 128),     # the benchmark's M
+    (16, 7, 384),
+    (10, 4, 1000),
+])
+def test_nonsmooth_objective_vs_ref(n, M, d):
+    """The split form at one x and under `vmap` over the stack of node
+    points, as the simulator's evaluation calls it."""
+    xs, centers = _nonsmooth(n, M, d, seed=n * M)
+    f = _split_objective(centers)
+    _assert_objective(f(xs[0]), xs[0], centers)
+    stacked = jax.jit(jax.vmap(f))(xs)
+    assert stacked.shape == (n,) and stacked.dtype == jnp.float32
+    for i in range(n):
+        _assert_objective(stacked[i], xs[i], centers)
+
+
+@pytest.mark.parametrize("n", [2, 16])
+def test_nonsmooth_objective_large_norm(n):
+    """x of large norm beside node 0's centres: there ||x||^2 is over a
+    thousand times ||x - c||^2, so those pairs' split terms cancel; the
+    other nodes' far pairs keep F itself of ||x||^2's size."""
+    M, d = 6, 256
+    rng = np.random.default_rng(n)
+    _, centers = _nonsmooth(n, M, d, seed=n)
+    shift = 30.0 * np.sign(rng.normal(size=d))
+    c = np.asarray(centers).copy()
+    c[0] = shift + rng.normal(0.0, 0.3, (M, 2, d))
+    centers = jnp.asarray(c, jnp.float32)
+    x = jnp.asarray(shift + rng.normal(0.0, 0.1, d), jnp.float32)
+    near = np.sum((np.asarray(x) - c[0]) ** 2, axis=-1)
+    assert float(jnp.sum(x * x)) > 1e3 * near.max()
+    _assert_objective(_split_objective(centers)(x), x, centers)
+
+
+@pytest.mark.parametrize("i,m,k", [(0, 0, 0), (3, 2, 1), (7, 5, 0)])
+def test_nonsmooth_objective_on_a_centre(i, m, k):
+    """x exactly on one centre of a pair: its own distance is 0 and the
+    max takes the pair's other centre."""
+    n, M, d = 8, 6, 256
+    _, centers = _nonsmooth(n, M, d, seed=5)
+    x = centers[i, m, k]
+    _assert_objective(_split_objective(centers)(x), x, centers)
+
+
+def test_nonsmooth_objective_cancellation_is_bounded_by_the_terms():
+    """Where F is far below the size of its split terms -- one node, x
+    among its own centres at a large norm -- the split loses digits in
+    proportion to that ratio and no more: its error stays within float32
+    rounding of M ||x||^2 + mean_i sum_j max_k ||c_ijk||^2."""
+    M, d = 4, 256
+    rng = np.random.default_rng(11)
+    shift = 30.0 * np.sign(rng.normal(size=d))
+    c = shift + rng.normal(0.0, 0.3, (1, M, 2, d))
+    x = jnp.asarray(shift + rng.normal(0.0, 0.1, d), jnp.float32)
+    centers = jnp.asarray(c, jnp.float32)
+    c64 = np.asarray(centers, np.float64)
+    scale = (M * float(np.sum(np.asarray(x, np.float64) ** 2))
+             + float(np.sum(np.max(np.sum(c64 * c64, -1), -1))))
+    want = _objective_f64(x, centers)
+    assert scale > 1e3 * want
+    got = float(_split_objective(centers)(x))
+    assert abs(got - want) <= 1e-6 * scale
+
+
+def test_nonsmooth_problem_objective_is_the_split_form():
+    """The `nonsmooth` problem evaluates F by the split form, its cross
+    terms one dot at "highest" precision, within 1e-6 of the direct form."""
+    from repro.experiments.components import nonsmooth_centers, problems
+    n, M, d, seed = 6, 4, 130, 3
+    prob = problems.build("nonsmooth", n=n, M=M, d=d, seed=seed)
+    centers = jnp.asarray(nonsmooth_centers(n, M, d, seed), jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(0), (d,), jnp.float32)
+    _assert_objective(jax.jit(prob.objective)(x), x, centers)
+    dots = [e for e in jax.make_jaxpr(prob.objective)(x).jaxpr.eqns
+            if e.primitive.name == "dot_general"]
+    assert len(dots) == 1
+    assert dots[0].params["precision"] == (jax.lax.Precision.HIGHEST,) * 2

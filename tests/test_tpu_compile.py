@@ -124,6 +124,36 @@ def test_nonsmooth_subgrad_compiles_for_v5e(one_chip):
                                centers).compile(), "vmap_nonsmooth_subgrad_")
 
 
+def test_nonsmooth_objective_compiles_to_a_highest_dot_for_v5e(one_chip):
+    """The section V.B objective vmapped over the n=256 node averages at
+    the benchmark's size (M=30, d=4096), as the simulator's evaluation
+    runs it: its cross terms against the (2nM, d) rows of centres compile
+    to one dot (XLA may write it as a convolution) at "highest" operand
+    precision, and no fusion of the direct form's f32[256,256,30,2]
+    squared distances, built from the elementwise differences, is left.
+    The direct form shows both checks can see it."""
+    n, pairs = N, 30
+    x = _sds((n, M), jnp.float32, one_chip)
+    centers = _sds((n, pairs, 2, M), jnp.float32, one_chip)
+    split = jax.jit(jax.vmap(ops.nonsmooth_objective, in_axes=(0, None, None)))
+    direct = jax.jit(jax.vmap(ref.nonsmooth_objective_ref, in_axes=(0, None)))
+    dot = re.compile(r"= f32\[[\d,]*\]\S* (convolution|dot)\(.*")
+    squared = re.compile(rf"fusion\S* = f32\[{n},{n},{pairs},2\]\S* fusion\(")
+
+    text = split.lower(x, _sds((n * pairs * 2, M), jnp.float32, one_chip),
+                       _sds((n, pairs, 2), jnp.float32, one_chip)
+                       ).compile().as_text()
+    dots = [m.group(0) for m in dot.finditer(text)]
+    assert len(dots) == 1, dots
+    assert "operand_precision={highest,highest}" in dots[0]
+    assert not squared.search(text)
+    assert f"f32[{n},{n},{pairs},2,{M}]" not in text
+
+    text = direct.lower(x, centers).compile().as_text()
+    assert not dot.search(text) and squared.search(text)
+    assert f"f32[{n},{n},{pairs},2,{M}]" in text
+
+
 def test_nonsmooth_scan_program_compiles_for_v5e(one_chip, monkeypatch):
     """The whole-run scan of the `nonsmooth` problem (n=8, M=30, d=4096),
     built and traced with the platform check steered to the TPU: its
