@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compress import build_compressor
 from repro.core import n_opt_complete
 from repro.core.dda import trace_time_to_reach
 from repro.experiments import ExperimentSpec, run as run_spec
@@ -53,12 +54,10 @@ def measure_r(m_pairs: int, d: int, seed: int,
 
 def cell_spec(n: int, m_pairs: int, d: int, T: int, A: float, r: float,
               seed: int, eval_every: int = 10,
-              compress_keep: float | None = None) -> ExperimentSpec:
+              keep: float | None = None) -> ExperimentSpec:
     """One Fig. 1 cell: n-node complete graph, communicate every iteration,
-    stepsize a(t) = A / sqrt(t) with the driver's measured scale."""
-    backend_params = ({}
-                      if compress_keep is None
-                      else {"compress_keep": compress_keep})
+    stepsize a(t) = A / sqrt(t) with the driver's measured scale; `keep`
+    sends top-k messages of that fraction with error feedback."""
     return ExperimentSpec(
         name="fig1_complete",
         problem={"kind": "metric_learning",
@@ -66,27 +65,29 @@ def cell_spec(n: int, m_pairs: int, d: int, T: int, A: float, r: float,
                             "seed": seed}},
         topology={"kind": "complete"},
         schedule={"kind": "every"},
-        backends=[{"kind": "dense", "params": backend_params}],
+        backends=[{"kind": "dense"}],
         stepsize={"kind": "sqrt", "params": {"A": A}},
+        compression=(None if keep is None
+                     else {"kind": "topk", "params": {"keep": keep}}),
         T=T, eval_every=eval_every, seed=seed, r=r)
 
 
 def run(m_pairs: int = 200_000, d: int = 24, n_max: int = 14, T: int = 300,
         eps_frac: float = 0.12, bandwidth_bps: float = PAPER_ETHERNET_BPS,
-        seed: int = 0, verbose: bool = True, compress_keep: float = None,
-        r_override: float = None):
+        seed: int = 0, verbose: bool = True, keep: float | None = None,
+        r_override: float | None = None):
     r, t_grad = measure_r(m_pairs, d, seed, bandwidth_bps)
-    if compress_keep is not None:
-        # [beyond paper] top-k+EF message compression cuts wire bytes
-        # (values + indices), and with them r -- paper eq. 11 then predicts
-        # a LARGER optimal cluster: n_opt = 1/sqrt(r * ratio).
-        from repro.core import ratio_bytes
-        r = r * ratio_bytes(compress_keep, 8, 4)
     if r_override is not None:
         r = r_override
-    nopt = n_opt_complete(r)
     prob1 = problems.build("metric_learning", n=1, m_pairs=m_pairs,
                            d_feat=d, seed=seed)
+    # [beyond paper] top-k+EF message compression cuts the wire bytes
+    # (values + indices) to a fraction c, and with them the effective
+    # tradeoff r*c that each cell's time axis charges -- paper eq. 11 then
+    # predicts a LARGER optimal cluster: n_opt = 1/sqrt(r * c).
+    c = (1.0 if keep is None
+         else build_compressor("topk", {"keep": keep}).wire_ratio(prob1.d))
+    nopt = n_opt_complete(r, c)
     f0 = prob1.f0()
     eps_target = eps_frac * f0
     # paper-optimal stepsize scale (eq. 18 with h=1, lam2=0): A = R/(L*sqrt(31))
@@ -100,7 +101,7 @@ def run(m_pairs: int = 200_000, d: int = 24, n_max: int = 14, T: int = 300,
         # m/n pairs, so the consensus direction shrinks ~1/n vs the n=1 run;
         # scaling a(t) by n keeps the effective step n-invariant.
         res = run_spec(cell_spec(n, m_pairs, d, T, n * A_scale, r, seed,
-                                 compress_keep=compress_keep))
+                                 keep=keep))
         tta = trace_time_to_reach(res.trace, eps_target)
         rows.append({"n": n, "time_to_eps": tta,
                      "final_F": res.trace.fvals[-1]})

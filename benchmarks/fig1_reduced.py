@@ -8,7 +8,7 @@ shrinks r by PCA-ing the PROBLEM (messages stay exact but are (87^2+1)/
 structure, so we apply the same message-byte ratio to the measured r
 directly and run DDA with exact mixing -- identical time-model semantics.
 (Lossy top-k+EF message compression is the beyond-paper alternative; it is
-exercised in benchmarks/fig1_complete.run(compress_keep=...) and unit
+exercised in benchmarks/fig1_complete.run(keep=...) and unit
 tested for convergence in tests/test_dda.py.)
 
 Like fig1_complete, every cell is an `ExperimentSpec` through `repro.run()`

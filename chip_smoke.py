@@ -9,9 +9,9 @@ One chip runs two phases:
     at n=256 nodes, M=30, d=4096 on a 4-regular expander, three times:
     communicate every iteration, every 4th (the mix under `lax.cond`), and
     top-k compressed gossip (the compress-mix kernel). Each compiled scan
-    program must hold the Pallas kernel (`tpu_custom_call`), and its F trace
-    must match the dense `P @ z` matmul reference run at "highest" matmul
-    precision to `DENSE_RTOL`.
+    program must hold the Pallas kernel (`tpu_custom_call`), and a second
+    run of the same program must give the same result. (`bench/run.py`
+    holds every DDA cell to its float32 reference, `bench/dda_ref.py`.)
   * serve -- an in-process `ExperimentServer` answering `Client` requests
     over TCP: a cold request, a warm repeat (a compile-cache hit, identical
     to a solo `repro.run()`), and a packed pair compared with its solo runs.
@@ -44,11 +44,10 @@ import time
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent / "src"))
 
-#: relative tolerance on F between the kernel path and the matmul reference.
-#: Both are float32 and differ only in the order (and, for the reference's
-#: multi-pass "highest" matmul, the rounding) of each mixing round's sums.
-#: Such differences grow at worst linearly in the rounds: T * eps_f32 =
-#: 300 * 1.19e-7 = 3.6e-5 at T=300; the tolerance leaves ~3x above that.
+#: relative tolerance on F between a packed lane and its solo run. Both are
+#: float32 and differ only in the order of each round's sums, which grows
+#: at worst linearly in the rounds: T * eps_f32 = 300 * 1.19e-7 = 3.6e-5 at
+#: T=300; the tolerance leaves ~3x above that.
 DENSE_RTOL = 1e-4
 
 #: the gossip check compares bfloat16 parameters, rounded twice on the way
@@ -98,7 +97,7 @@ def _canon(result) -> str:
 
 def nonsmooth_spec(name: str, *, n: int, M: int, d: int, T: int,
                    eval_every: int, schedule: dict, compression=None,
-                   backend_params=None, r: float = 0.01):
+                   r: float = 0.01):
     from repro.experiments import ExperimentSpec
     return ExperimentSpec(
         name=name,
@@ -106,7 +105,7 @@ def nonsmooth_spec(name: str, *, n: int, M: int, d: int, T: int,
                  "params": {"n": n, "M": M, "d": d, "seed": 0}},
         topology={"kind": "expander", "params": {"k": 4, "seed": 0}},
         schedule=schedule,
-        backends=[{"kind": "dense", "params": dict(backend_params or {})}],
+        backends=[{"kind": "dense"}],
         # the stepsize of the repo's fig2_sparse manifest (same problem)
         stepsize={"kind": "sqrt", "params": {"A": 0.004}},
         compression=compression, T=T, eval_every=eval_every, seed=0, r=r)
@@ -148,8 +147,6 @@ DENSE_CASES = (
 
 def dense_phase(n: int = 256, M: int = 30, d: int = 4096, T: int = 300,
                 eval_every: int = 25) -> dict:
-    import jax
-
     import repro
 
     tpu = _on_tpu()
@@ -170,27 +167,15 @@ def dense_phase(n: int = 256, M: int = 30, d: int = 4096, T: int = 300,
                    f"{n_prog} programs lack the Pallas kernel")
         _check(_canon(again) == _canon(res),
                f"{name}: a second run of the same program differs")
-        with jax.default_matmul_precision("highest"):
-            ref = repro.run(nonsmooth_spec(f"dense_{name}_ref",
-                                           backend_params={"mix": "dense"},
-                                           **shape))
-        _check(ref.extras["mix_mode"] == "dense", "reference is not dense")
-        rel = _rel(res.trace.fvals, ref.trace.fvals)
         row = {"compile_s": res.metrics.compile_s,
                "execute_s": res.metrics.execute_s,
                "wait_s": res.metrics.phases["wait"]["total_s"],
                "final_F": res.trace.fvals[-1],
                "peak_bytes_in_use": peak,
-               "kernel_programs": f"{n_kernel}/{n_prog}",
-               "ref_compile_s": ref.metrics.compile_s,
-               "ref_final_F": ref.trace.fvals[-1],
-               "rel_diff": rel}
+               "kernel_programs": f"{n_kernel}/{n_prog}"}
         print(f"[dense] {name}: " + " ".join(f"{k}={v!r}"
                                              for k, v in row.items()),
               flush=True)
-        _check(rel <= DENSE_RTOL,
-               f"{name}: F differs from the matmul reference by {rel!r} "
-               f"(tolerance {DENSE_RTOL})")
         out[name] = row
     return out
 

@@ -15,9 +15,9 @@ carries both its numpy (netsim) and jax (dense) execution halves:
     vectorized engines bit-identical under compression: each node's sends
     occur in increasing stamp order in both engines, so per-node residual
     sequences coincide exactly;
-  * a per-message byte model (`wire_ratio(d)`), the generalized
-    `core.compression.ratio_bytes`: the fraction of the uncompressed
-    d-float payload that actually crosses the wire. This is the c in the
+  * a per-message byte model (`wire_ratio(d)`): the fraction of the
+    uncompressed d-float payload that actually crosses the wire (top-k:
+    k values plus k indices over d values). This is the c in the
     paper's effective tradeoff r -> r*c (n_opt = 1/sqrt(rc), h_opt ~
     sqrt(nkrc)); `netsim.Network` scales its serialization times by it and
     `core.tradeoff` accepts it as the `c=` argument everywhere.

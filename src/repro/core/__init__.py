@@ -19,9 +19,4 @@ from repro.core.tradeoff import (TPU_V5E, HardwareSpec, derive_r_from_roofline,
 from repro.core.consensus import (disagreement, mix_collective, mix_dense,
                                   mix_stale, stale_combine, stale_combine_batch,
                                   tree_mix_collective, tree_mix_dense)
-from repro.core.dda import (DDASimulator, DDAState, SimTrace, dda_init,
-                            dda_local_step, dda_mix_step, stepsize_sqrt)
-from repro.core.compression import (CompressionState, ef_compress, ef_init,
-                                    ratio_bytes, topk_compress,
-                                    topk_decompress)
-from repro.core.consensus_sgd import ConsensusConfig, mix_params, mix_params_dense
+from repro.core.dda import DDASimulator, SimTrace, stepsize_sqrt

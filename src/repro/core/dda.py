@@ -10,22 +10,23 @@ with psi(x) = 0.5 ||x||^2 the proximal step is x = Proj_X(-a(t) z) (paper V.A).
 On cheap iterations (no communication) the consensus sum is replaced by
 z_i(t) = z_i(t-1) + g_i(t-1)  (paper IV.A).
 
-Two execution modes:
+`DDASimulator` runs it with the n nodes as a stacked leading axis of
+(n, ...) arrays on one device:
 
-  * `DDASimulator` -- stacked (n, ...) arrays on one device. Mixing is the
-    dense P matmul oracle or, for k-regular graphs, the sparse fast path
-    (neighbor-index gather + the fused `kernels.ops.gossip_gather_mix`
-    accumulation, O(nkd) instead of O(n^2 d)); the whole run executes as
-    ONE compiled scan over precomputed comm-mask data (see `run`), with
-    `run_batch` vmapping sweep lanes. Bit-faithful to the paper's
-    algorithm; used for the paper's experiments (benchmarks/fig*) and as
-    the oracle for the distributed mode.
-  * `dda_local_step` / `dda_mix_step` -- per-shard pytree updates with
-    `mix_collective` over a mesh axis, used by the production launcher. Both
-    are pure and jit/shard_map friendly; the schedule (which step type to run)
-    is decided by the host launcher, never by traced control flow, so each
-    variant compiles to a collective-free / collective-bearing program
-    respectively.
+  * `run` executes the whole run as ONE compiled scan over the comm mask
+    the schedule precomputes, with the trace statistics evaluated on the
+    device; `run_batch` vmaps that program over sweep lanes; and
+    `run_adaptive` drives the per-chunk segment program for a controller
+    that retunes the schedule from wall-clock timings.
+  * The mix is chosen from the graph: a k-regular graph materially
+    sparser than complete gathers its neighbours into the fused
+    `kernels.ops.gossip_gather_mix` accumulation (O(nkd)); any other
+    graph multiplies by the dense P (O(n^2 d)).
+  * Compression is a built `repro.compress.Compressor` whose error
+    feedback rides in the scanned carry.
+
+The paper's experiments (benchmarks/fig*) and the experiments runner's
+"dense" backend run it.
 """
 
 from __future__ import annotations
@@ -34,7 +35,7 @@ import contextlib
 import dataclasses
 import math
 import time
-from typing import Any, Callable, NamedTuple, Sequence
+from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -45,10 +46,6 @@ from repro.core.graphs import CommGraph
 from repro.core.schedules import CommSchedule, EveryIteration
 
 __all__ = [
-    "DDAState",
-    "dda_init",
-    "dda_local_step",
-    "dda_mix_step",
     "DDASimulator",
     "SimTrace",
     "TRACE_FIELDS",
@@ -56,8 +53,6 @@ __all__ = [
     "stepsize_sqrt",
     "trace_time_to_reach",
 ]
-
-PyTree = Any
 
 
 def stepsize_sqrt(A: float, q: float = 0.5) -> Callable[[jax.Array], jax.Array]:
@@ -76,51 +71,6 @@ def stepsize_sqrt(A: float, q: float = 0.5) -> Callable[[jax.Array], jax.Array]:
         xp = jnp if isinstance(t, jax.Array) else np
         return A / xp.maximum(t, 1.0) ** q
     return a
-
-
-class DDAState(NamedTuple):
-    z: PyTree      # accumulated dual (subgradient) direction
-    x: PyTree      # current primal iterate
-    xhat: PyTree   # running average (the algorithm's output)
-    t: jax.Array   # iteration counter (float32 scalar for stable division)
-
-
-def dda_init(x0: PyTree) -> DDAState:
-    zeros = jax.tree.map(jnp.zeros_like, x0)
-    return DDAState(z=zeros, x=x0, xhat=x0, t=jnp.asarray(0.0, jnp.float32))
-
-
-def _prox(z: PyTree, a_t: jax.Array, projection: Callable[[PyTree], PyTree] | None) -> PyTree:
-    x = jax.tree.map(lambda zl: (-a_t * zl).astype(zl.dtype), z)
-    return projection(x) if projection is not None else x
-
-
-def _advance(state: DDAState, z_new: PyTree, a_fn, projection) -> DDAState:
-    t_new = state.t + 1.0
-    x_new = _prox(z_new, a_fn(t_new), projection)
-    xhat_new = jax.tree.map(
-        lambda h, x: (state.t * h + x) / t_new, state.xhat, x_new)
-    return DDAState(z=z_new, x=x_new, xhat=xhat_new, t=t_new)
-
-
-def dda_local_step(state: DDAState, grad: PyTree, a_fn,
-                   projection: Callable | None = None) -> DDAState:
-    """Cheap iteration: z <- z + g (no communication)."""
-    z_new = jax.tree.map(jnp.add, state.z, grad)
-    return _advance(state, z_new, a_fn, projection)
-
-
-def dda_mix_step(state: DDAState, grad: PyTree, graph: CommGraph,
-                 axis_name: str, a_fn,
-                 projection: Callable | None = None) -> DDAState:
-    """Expensive iteration: z <- P z + g (consensus + subgradient).
-
-    Must be called inside shard_map with `axis_name` mapping the consensus
-    axis (one DDA node per index).
-    """
-    mixed = _cons.tree_mix_collective(state.z, graph, axis_name)
-    z_new = jax.tree.map(jnp.add, mixed, grad)
-    return _advance(state, z_new, a_fn, projection)
 
 
 # ---------------------------------------------------------------------------
@@ -231,6 +181,13 @@ def _program_name(kind: tuple) -> str:
 class DDASimulator:
     """Runs DDA with n nodes as a stacked leading axis on one device.
 
+    `run` is one scanned program over the whole run, `run_batch` vmaps it
+    over sweep lanes, and `run_adaptive` dispatches the segment program
+    chunk by chunk for a wall-clock controller. The mix is chosen from the
+    graph (`mix_mode`): "sparse", the neighbour gather into the fused
+    `kernels.ops.gossip_gather_mix` pass, when the graph has a permutation
+    edge set and k + 1 < n; otherwise "dense", the P @ z matmul.
+
     The traced programs name their layers with `jax.named_scope`s, which
     change only op metadata and which a device trace keeps per op:
     `dda.subgrad`, `dda.mix` (holding `dda.compress`, the compressor's
@@ -242,9 +199,7 @@ class DDASimulator:
       subgrad_fn: (x_stack[n, ...], t) -> g_stack[n, ...]; node i's
         subgradient of f_i at x_i. Deterministic (batch) or stochastic.
       eval_fn: x[...] -> scalar F(x) on the FULL objective. Must be
-        jax-traceable: the default scanned loop evaluates the trace
-        device-side (use `run(..., loop="segment")` for a host-only
-        eval_fn).
+        jax-traceable: the scanned run evaluates the trace device-side.
       graph: communication topology (mixing matrix P taken from it).
       schedule: communication schedule (every / periodic-h / sparse-p).
       a_fn: stepsize a(t).
@@ -258,37 +213,11 @@ class DDASimulator:
         always mixes the node's exact own z -- only RECEIVED messages are
         compressed. `self.wire_ratio(d)` exposes the byte model for the
         effective tradeoff r -> r*c.
-      compress_keep: legacy alias ([beyond paper], kept for back-compat):
-        `compress_keep=f` is exactly `compression=TopK(keep=f)`. Mutually
-        exclusive with `compression`.
-      mix: "auto" | "dense" | "sparse" mixing realization. "dense" is the
-        P @ z matmul oracle (the seed path; O(n^2 d)). "sparse" is the
-        k-regular fast path: a neighbor-index gather + the fused
-        `kernels.ops.gossip_gather_mix` accumulation (O(n k d)) -- the
-        paper's degree-scaling communication argument applied to the
-        simulator's own memory traffic. "auto" picks sparse whenever the
-        graph's permutation edge set is materially sparser than complete
-        (k + 1 < n) and any `mix_weights` override is supported on the
-        edge set; it falls back to dense otherwise (the resolved choice
-        is exposed as `self.mix_mode`). Compression no longer disqualifies
-        the sparse path: compressed messages ride the fused compress-mix
-        kernel (`kernels.ops.compress_mix`) there.
-      mix_weights: optional (n, n) mixing-matrix override (e.g. the
-        straggler-reweighted effective P from
-        `AdaptiveController(reweight_gossip=True)`). The sparse path folds
-        it into per-edge weight vectors (slot weight W[i, src] /
-        multiplicity, the netsim engines' convention); a matrix with
-        weight OUTSIDE the graph's edge-plus-diagonal support cannot be
-        gathered along edges, so it automatically falls back to the dense
-        matmul ("non-regular" in the kernel's sense).
     """
 
     def __init__(self, subgrad_fn, eval_fn, graph: CommGraph,
                  schedule: CommSchedule | None = None,
                  a_fn=None, projection=None, r: float = 0.0,
-                 compress_keep: float | None = None,
-                 mix: str = "auto",
-                 mix_weights: np.ndarray | None = None,
                  compression=None):
         self.subgrad_fn = subgrad_fn
         self.eval_fn = eval_fn
@@ -297,27 +226,17 @@ class DDASimulator:
         self.a_fn = a_fn or stepsize_sqrt(1.0)
         self.projection = projection
         self.r = float(r)
-        if compress_keep is not None and compression is not None:
-            raise ValueError("pass either compression or the legacy "
-                             "compress_keep alias, not both")
-        if compress_keep is not None:
-            from repro.compress import TopK
-            compression = TopK(keep=float(compress_keep))
-        self.compress_keep = compress_keep
         # "none" normalizes to no compression so the uncompressed program
         # (and its compile cache keys) is byte-for-byte the seed program
         if compression is not None and compression.kind == "none":
             compression = None
         self.compression = compression
-        self.mix_weights = (None if mix_weights is None
-                            else np.asarray(mix_weights, np.float64))
-        self.mix_mode = self._resolve_mix_mode(mix)
+        self.mix_mode = self._resolve_mix_mode()
         #: per-segment mean per-node error-feedback residual norms of the
-        #: last run/run_batch (np (S,) or (B, S)); zeros when uncompressed
+        #: last run (np (S,), or (B, S) from run_batch); zeros when
+        #: uncompressed, None after an uncompressed run_adaptive
         self.last_res_norms: np.ndarray | None = None
-        P_host = (self.mix_weights if self.mix_weights is not None
-                  else graph.mixing_matrix())
-        self._P = jnp.asarray(P_host, jnp.float32)
+        self._P = jnp.asarray(graph.mixing_matrix(), jnp.float32)
         # off-diagonal mixing applies to RECEIVED (possibly compressed)
         # messages; the diagonal always uses the node's exact own state.
         self._P_off = self._P - jnp.diag(jnp.diag(self._P))
@@ -454,8 +373,7 @@ class DDASimulator:
         # the top of every run/run_batch and read by the experiments runner
         # to populate RunMetrics.compile_s / execute_s.
         self._compiled: dict[tuple, Any] = {}
-        self.last_timings: dict[str, float] = {"compile_s": 0.0,
-                                               "eval_s": 0.0}
+        self.last_timings: dict[str, float] = {"compile_s": 0.0}
         #: a `repro.obs.Tracer` that a caller binds for one run (the
         #: experiments runner does): compiles, program calls and result
         #: assembly open host spans on it, and so on the profiler's clock
@@ -465,7 +383,7 @@ class DDASimulator:
     # -- timed dispatch ------------------------------------------------------
 
     def _reset_timings(self) -> None:
-        self.last_timings = {"compile_s": 0.0, "eval_s": 0.0}
+        self.last_timings = {"compile_s": 0.0}
         self.last_res_norms = None
 
     def wire_ratio(self, d: int) -> float:
@@ -523,78 +441,41 @@ class DDASimulator:
 
     # -- mix-mode resolution -------------------------------------------------
 
-    def _resolve_mix_mode(self, mix: str) -> str:
-        if mix not in ("auto", "dense", "sparse"):
-            raise ValueError(f"mix must be auto/dense/sparse, got {mix!r}")
-        if mix == "dense":
-            return "dense"
-        # NOTE: compression deliberately does NOT appear here anymore --
-        # compressed messages ride the fused compress-mix kernel (or the
-        # msg= gather for quantizers) on the sparse path.
-        reasons = []
-        if not self.graph.perms:
-            reasons.append("graph has no permutation edge set")
-        elif self.graph.degree + 1 >= self.graph.n:
-            reasons.append("graph is (near-)complete: the matmul moves "
-                           "less memory than a degree-(n-1) gather")
-        if self.mix_weights is not None and not self._edge_supported():
-            reasons.append("mix_weights has weight outside the graph's "
-                           "edge support (non-regular P)")
-        if reasons:
-            if mix == "sparse":
-                raise ValueError("sparse mix unavailable: "
-                                 + "; ".join(reasons))
-            return "dense"
-        return "sparse"
+    def _resolve_mix_mode(self) -> str:
+        """"sparse" when the graph has a permutation edge set materially
+        sparser than complete (k + 1 < n), else "dense": past that, the
+        matmul moves less memory than a degree-(n-1) gather. Compressed
+        messages ride the fused compress-mix kernel (or the msg= gather
+        for quantizers) on the sparse path, so compression does not
+        decide."""
+        g = self.graph
+        if g.perms and g.degree + 1 < g.n:
+            return "sparse"
+        return "dense"
 
-    def _edge_supported(self) -> bool:
-        """True if mix_weights only places weight on self-loops + edges."""
-        W = self.mix_weights
-        n = self.graph.n
-        allowed = np.eye(n, dtype=bool)
-        for perm in self.graph.perms:
-            allowed[np.arange(n), np.asarray(perm)] = True
-        return not np.any((W != 0.0) & ~allowed)
-
-    def _sparse_weights(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    def _sparse_weights(self) -> tuple[np.ndarray, np.float32, np.float32]:
         """(S_in, w_self, w_edge) for the gather path. S_in[i, j] is the
-        node whose value node i receives in permutation slot j. A
-        `mix_weights` override folds through the shared
-        `graphs.mix_weight_slots` convention (W[i, src] / multiplicity per
-        slot), keeping dense and netsim reweighted gossip comparable."""
+        node whose value node i receives in permutation slot j; the scalar
+        weights let the op scale the SUM of the gathers once instead of
+        broadcasting k weight columns."""
         g = self.graph
         S_in = np.stack([np.asarray(p, dtype=np.int64) for p in g.perms],
                         axis=1)  # (n, k)
-        if self.mix_weights is None:
-            # scalar weights: the op's uniform path scales the SUM of the
-            # gathers once instead of broadcasting k weight columns
-            return (S_in, np.float32(g.self_weight),
-                    np.float32(g.edge_weight))
-        from repro.core.graphs import mix_weight_slots
-        w_slot, w_self = mix_weight_slots(self.mix_weights, S_in)
-        return (S_in, w_self.astype(np.float32),
-                w_slot.astype(np.float32))
+        return S_in, np.float32(g.self_weight), np.float32(g.edge_weight)
 
     # -- run loops -----------------------------------------------------------
 
     def run(self, x0_stack: jax.Array, T: int, eval_every: int = 25,
-            seed: int = 0, loop: str = "scan") -> SimTrace:
+            seed: int = 0) -> SimTrace:
         """Run T iterations, evaluating every `eval_every`.
 
-        loop="scan" (default): the whole run is one compiled program per
-        distinct segment length (at most two: the full segments and a
-        remainder), with the comm pattern precomputed host-side by
-        `CommSchedule.comm_mask` and fed as data. loop="segment" keeps the
-        legacy host loop -- one dispatch per evaluation segment with the
-        trace statistics computed eagerly -- for host-only eval_fns and as
-        the seed baseline `benchmarks/bench_dense.py` times against.
+        The whole run is one compiled program per distinct segment length
+        (at most two: the full segments and a remainder), with the comm
+        pattern precomputed host-side by `CommSchedule.comm_mask` and fed
+        as data.
         """
         n = self.graph.n
         assert x0_stack.shape[0] == n, "x0 must be stacked (n, ...)"
-        if loop == "segment":
-            return self._run_segment_loop(x0_stack, T, eval_every, seed)
-        if loop != "scan":
-            raise ValueError(f"loop must be 'scan' or 'segment', got {loop!r}")
         self._reset_timings()
         # the run's arguments: its comm pattern, initial state and key
         with self._span("dispatch"):
@@ -621,7 +502,7 @@ class DDASimulator:
             state, out = self._timed_call(("scan", ac), prog,
                                           (state, masks, starts, root))
             outs.append(out)
-        if not outs:  # T == 0: an empty trace, as the legacy loop returns
+        if not outs:  # T == 0: an empty trace
             return SimTrace([], [], [], [], [])
         with self._span("assemble"):
             fv, fvc, dis, rn = (np.concatenate([np.asarray(o[i])
@@ -639,8 +520,7 @@ class DDASimulator:
     def _assemble_trace(self, mask_full, T, eval_every, r,
                         fv, fvc, dis) -> SimTrace:
         """Host bookkeeping: the simulated time axis (eq. 9 charges) from
-        the precomputed comm mask, accumulated segment-by-segment in the
-        exact float order of the legacy loop."""
+        the precomputed comm mask, accumulated segment by segment."""
         n, k = self.graph.n, self.graph.degree
         trace = SimTrace([], [], [], [], [])
         sim_time = 0.0
@@ -660,44 +540,6 @@ class DDASimulator:
             trace.comms.append(comm_total)
             trace.disagreement.append(float(dis[idx]))
             idx += 1
-        return trace
-
-    def _run_segment_loop(self, x0_stack, T, eval_every, seed) -> SimTrace:
-        self._reset_timings()
-        z = jnp.zeros_like(x0_stack)
-        x = x0_stack
-        xhat = x0_stack
-        res = jnp.zeros_like(x0_stack)
-        t = jnp.asarray(0.0, jnp.float32)
-        n, k = self.graph.n, self.graph.degree
-        r_eff = self.r * self.wire_ratio(int(np.prod(x0_stack.shape[1:])))
-        trace = SimTrace([], [], [], [], [])
-        sim_time = 0.0
-        comm_total = 0
-        root = jax.random.PRNGKey(seed)
-
-        done = 0
-        while done < T:
-            seg = min(eval_every, T - done)
-            mask = np.array([self.schedule.is_comm_step(done + i + 1)
-                             for i in range(seg)])
-            keys = jax.random.split(jax.random.fold_in(root, done), seg)
-            z, x, xhat, res, t = self._timed_call(
-                ("segment",), self._segment,
-                (z, x, xhat, res, t, jnp.asarray(mask), keys))
-            done += seg
-            n_comm = int(mask.sum())
-            comm_total += n_comm
-            sim_time += seg * (1.0 / n) + n_comm * k * r_eff
-            t_eval = time.perf_counter()
-            xbar = jnp.mean(xhat, axis=0)
-            trace.iters.append(done)
-            trace.sim_time.append(sim_time)
-            trace.fvals.append(float(jnp.mean(jax.vmap(self.eval_fn)(xhat))))
-            trace.fvals_consensus.append(float(self.eval_fn(xbar)))
-            trace.comms.append(comm_total)
-            trace.disagreement.append(float(_cons.disagreement(z)))
-            self.last_timings["eval_s"] += time.perf_counter() - t_eval
         return trace
 
     def run_batch(self, x0_stack: jax.Array, T: int, eval_every: int,
@@ -751,7 +593,7 @@ class DDASimulator:
             state, out = self._timed_call(("vmap", ac), vprog,
                                           (state, m, starts, roots))
             outs.append(out)
-        if not outs:  # T == 0: empty traces, as the legacy loop returns
+        if not outs:  # T == 0: empty traces
             return [SimTrace([], [], [], [], []) for _ in range(B)]
         fv, fvc, dis, rn = (np.concatenate([np.asarray(o[i]) for o in outs],
                                            axis=1) for i in range(4))
@@ -759,6 +601,99 @@ class DDASimulator:
         return [self._assemble_trace(masks[b], T, eval_every, rs[b],
                                      fv[b], fvc[b], dis[b])
                 for b in range(B)]
+
+    def run_adaptive(self, x0_stack: jax.Array, T: int, eval_every: int,
+                     seed: int, controller, *,
+                     timer: Callable[[], float] = time.perf_counter,
+                     timings: dict[str, Any] | None = None) -> SimTrace:
+        """`run` with a measure->predict->act loop on the wall clock.
+
+        Each evaluation segment is split into uniform-comm chunks, and
+        each chunk is dispatched through the segment program's AOT compile
+        cache (shape-keyed; the comm mask is data) and timed on `timer`,
+        blocking on the device. `controller` is used through three calls:
+        `bind(n, k, lam2)` once, `observe(per_iter, comm)` for every
+        iteration, and `maybe_retune(done)` at each segment boundary, where
+        it may splice a re-solved h into the schedule (`self.schedule`)
+        from the frontier `done`, the number of iterations already run, so
+        a splice shapes only masks not yet built.
+
+        Compiling AOT *outside* the timed window keeps the controller's
+        measurements clean: a compile-bearing call would poison t_plain /
+        t_comm by orders of magnitude (with h0=1 the single t=1 plain chunk
+        is the ONLY plain sample until the first retune, and a
+        compile-inflated t_plain latches r_hat at 0 forever). The
+        executables share the cache `run`/`run_batch` use, so a warm
+        simulator (e.g. one the serving layer's compile cache holds) pays
+        no compile at all.
+
+        `timings` (optional dict) receives the observability record: the
+        AOT compile walls (always on the REAL clock -- `timer` only drives
+        the controller's measurements) accumulate into
+        `timings["compile_s"]`, and each iteration's measured wall appends
+        to `timings["iter_walls"]`.
+        """
+        n, k = self.graph.n, self.graph.degree
+        r_eff = self.r * self.wire_ratio(int(np.prod(x0_stack.shape[1:])))
+        controller.bind(n, k, self.graph.lambda2())
+        sched = self.schedule
+        z = jnp.zeros_like(x0_stack)
+        x = x0_stack
+        xhat = x0_stack
+        res = jnp.zeros_like(x0_stack)
+        t = jnp.asarray(0.0, jnp.float32)
+        trace = SimTrace([], [], [], [], [])
+        res_norms: list[float] = []
+        sim_time = 0.0
+        comm_total = 0
+        root = jax.random.PRNGKey(seed)
+
+        done = 0
+        while done < T:
+            seg_end = min(done + eval_every, T)
+            while done < seg_end:
+                comm = sched.is_comm_step(done + 1)
+                chunk = 1
+                while (done + chunk < seg_end
+                       and sched.is_comm_step(done + chunk + 1) == comm):
+                    chunk += 1
+                mask = np.full(chunk, comm)
+                keys = jax.random.split(jax.random.fold_in(root, done), chunk)
+                args = (z, x, xhat, res, t, jnp.asarray(mask), keys)
+                tw = time.perf_counter()
+                entry = self._get_compiled(("segment",), self._segment, args)
+                if timings is not None:
+                    timings["compile_s"] += time.perf_counter() - tw
+                fn = self._segment if entry is None else entry
+                t0 = timer()
+                z, x, xhat, res, t = fn(*args)
+                jax.block_until_ready(xhat)
+                per_iter = max(timer() - t0, 0.0) / chunk
+                if timings is not None:
+                    timings["iter_walls"].extend([per_iter] * chunk)
+                for _ in range(chunk):
+                    controller.observe(per_iter, comm)
+                done += chunk
+                if comm:
+                    comm_total += chunk
+                    sim_time += chunk * (1.0 / n + k * r_eff)
+                else:
+                    sim_time += chunk * (1.0 / n)
+            xbar = jnp.mean(xhat, axis=0)
+            trace.iters.append(done)
+            trace.sim_time.append(sim_time)
+            trace.fvals.append(float(jnp.mean(jax.vmap(self.eval_fn)(xhat))))
+            trace.fvals_consensus.append(float(self.eval_fn(xbar)))
+            trace.comms.append(comm_total)
+            trace.disagreement.append(float(_cons.disagreement(z)))
+            if self.compression is not None:
+                res_norms.append(float(jnp.mean(jnp.linalg.norm(
+                    res.reshape(n, -1), axis=1))))
+            if done < T:  # a splice at the frontier T would shape zero
+                controller.maybe_retune(done)  # iterations: no phantoms
+        self.last_res_norms = (np.asarray(res_norms)
+                               if self.compression is not None else None)
+        return trace
 
     def time_to_reach(self, trace: SimTrace, eps_value: float,
                       use_consensus: bool = False) -> float:

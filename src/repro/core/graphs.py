@@ -349,11 +349,11 @@ def mix_weight_slots(W: np.ndarray, S_in: np.ndarray
     (n,) self weights), both float64.
 
     This is THE definition of the reweighted-gossip slot convention: the
-    dense simulator's sparse mix (`core.dda.DDASimulator`) and the netsim
-    vectorized engine's stale mix both fold through here, which is what
-    keeps `AdaptiveController(reweight_gossip=True)` runs comparable
-    across execution modes (tests/test_kernels.py pins the convention
-    against the dense-matmul oracle independently).
+    netsim vectorized engine's stale mix folds through here and
+    `AsyncDDANode._stale_mix` applies it per node, which is what keeps
+    `AdaptiveController(reweight_gossip=True)` runs comparable across
+    engines (tests/test_kernels.py pins the convention against the
+    dense-matmul oracle independently).
     """
     W = np.asarray(W, dtype=np.float64)
     n, k = S_in.shape

@@ -12,11 +12,10 @@ mutable schedule state can never leak between runs.
 
 Backends (the `backends` registry):
 
-  * "dense"  -- DDASimulator on the stacked jax path. With a
-    "dense_adaptive" controller the segment loop is driven here, timing
-    uniform-comm chunks and feeding `adaptive.DenseController` so h retunes
-    from WALL-CLOCK iteration timings (the eq. 9 inversion of
-    DenseRTracker).
+  * "dense"  -- DDASimulator on the stacked jax path. A "dense_adaptive"
+    controller runs through `DDASimulator.run_adaptive`, which feeds
+    `adaptive.DenseController` timed uniform-comm chunks so h retunes from
+    WALL-CLOCK iteration timings (the eq. 9 inversion of DenseRTracker).
   * "netsim" -- NetSimulator on a scenario preset (params pick the preset
     and its knobs, plus engine / algorithm / adaptive controller).
   * "launch" -- a ConsensusProgram on a host mesh (params pick mesh
@@ -30,12 +29,11 @@ from __future__ import annotations
 import dataclasses
 import math
 import time
-from typing import Any, Callable, Sequence
+from typing import Any, Sequence
 
 import numpy as np
 
 from repro.core.dda import (DDASimulator, SimTrace, trace_time_to_reach)
-from repro.core import consensus as _cons
 from repro.core import tradeoff as _tradeoff
 from repro.core.graphs import CommGraph, GraphSequence
 from repro.experiments import components as C
@@ -172,23 +170,16 @@ def _dense_message_counts(trace: SimTrace, n: int, k: int, d: int,
 def _dense_parts(spec: ExperimentSpec, backend: ComponentSpec
                  ) -> dict[str, Any]:
     """Validate a dense run and build everything BUT the simulator: the
-    problem, graph, schedule and stepsize closures plus the parsed backend
-    params. One definition shared by the serial backend, the vmapped sweep
-    executor and the serving layer, so their validation can never drift."""
+    problem, graph, schedule and stepsize closures and the compressor. One
+    definition shared by the serial backend, the vmapped sweep executor
+    and the serving layer, so their validation can never drift."""
     _require(spec.faults is None,
              "fault injection is event-driven (netsim backends only); the "
              "dense synchronous loop has no crash/recover semantics")
-    params = dict(backend.params)
-    compress_keep = params.pop("compress_keep", None)
-    mix = params.pop("mix", "auto")
-    loop = params.pop("loop", "scan")
-    _require(not params, f"dense backend has unknown params {sorted(params)}")
+    _require(not backend.params,
+             f"dense backend has unknown params {sorted(backend.params)}")
     compression = None
     if spec.compression is not None:
-        _require(compress_keep is None,
-                 "backend param 'compress_keep' and spec.compression are "
-                 "mutually exclusive; spec.compression is the canonical "
-                 "compression axis (kind 'topk' subsumes compress_keep)")
         from repro.compress import build_compressor
         compression = build_compressor(spec.compression.kind,
                                        dict(spec.compression.params))
@@ -208,26 +199,23 @@ def _dense_parts(spec: ExperimentSpec, backend: ComponentSpec
              "time_limit is event-clock only (netsim backends)")
     return dict(problem=problem, graph=graph,
                 schedule=_build_schedule(spec),
-                a_fn=_build_stepsize(spec),
-                compress_keep=compress_keep, compression=compression,
-                mix=mix, loop=loop)
+                a_fn=_build_stepsize(spec), compression=compression)
 
 
 def _dense_sim(spec: ExperimentSpec, parts: dict[str, Any]) -> DDASimulator:
     """Fresh DDASimulator from `_dense_parts` output. Everything that
     shapes the simulator's compiled programs (problem closures, graph,
-    stepsize, mix/compression realization) comes from fields the serving
-    layer's `cache_signature` pins, which is what makes instances reusable
-    across requests: per-request knobs (schedule, r) are rebound by the
-    caller before each run."""
+    stepsize, compression) comes from fields the serving layer's
+    `cache_signature` pins, which is what makes instances reusable across
+    requests: per-request knobs (schedule, r) are rebound by the caller
+    before each run."""
     import jax
     problem = parts["problem"]
     return DDASimulator(problem.subgrad_stack, jax.jit(problem.objective),
                         parts["graph"], parts["schedule"],
                         a_fn=parts["a_fn"], r=spec.r,
-                        compress_keep=parts["compress_keep"],
                         compression=parts["compression"],
-                        mix=parts["mix"], projection=problem.projection)
+                        projection=problem.projection)
 
 
 @backends.register("dense")
@@ -250,7 +238,7 @@ def _run_dense(spec: ExperimentSpec, backend: ComponentSpec,
         with tr.span("build"):
             parts = _dense_parts(spec, backend)
             problem, graph = parts["problem"], parts["graph"]
-            schedule, loop = parts["schedule"], parts["loop"]
+            schedule = parts["schedule"]
             if sim_cache is None:
                 lease = contextlib.nullcontext((_dense_sim(spec, parts),
                                                 False))
@@ -267,20 +255,16 @@ def _run_dense(spec: ExperimentSpec, backend: ComponentSpec,
             sim.r = spec.r
             tr.count("cache_hit" if cache_hit else "cache_miss")
         return _run_dense_leased(spec, backend, tr, sim, problem, graph,
-                                 schedule, loop, x0)
+                                 schedule, x0)
 
 
 def _run_dense_leased(spec: ExperimentSpec, backend: ComponentSpec,
                       tr: Tracer, sim: DDASimulator, problem, graph,
-                      schedule, loop: str, x0) -> RunResult:
-    import jax.numpy as jnp  # noqa: F401  (kept: jnp used below)
+                      schedule, x0) -> RunResult:
     extras: dict[str, Any] = {"mix_mode": sim.mix_mode}
 
     metrics_fields: dict[str, Any] = {}
     if spec.controller is not None:
-        _require(loop == "scan",
-                 "a dense_adaptive run drives its own wall-clock chunked "
-                 "segment loop; leave the 'loop' param unset")
         _require(spec.controller.kind == "dense_adaptive",
                  f"dense backend needs a 'dense_adaptive' controller, got "
                  f"{spec.controller.kind!r}")
@@ -299,9 +283,8 @@ def _run_dense_leased(spec: ExperimentSpec, backend: ComponentSpec,
         timings: dict[str, Any] = {"compile_s": 0.0, "iter_walls": []}
         t0 = time.perf_counter()
         with tr.span("execute"), profile_ctx(spec.profile_dir):
-            trace = _dense_adaptive_run(sim, ctrl, x0, spec.T,
-                                        spec.eval_every, spec.seed,
-                                        timings=timings)
+            trace = sim.run_adaptive(x0, spec.T, spec.eval_every,
+                                     spec.seed, ctrl, timings=timings)
         wall = time.perf_counter() - t0
         extras["retunes"] = [(rt.from_t, rt.h) for rt in schedule.retunes]
         extras["h_final"] = schedule.h_current
@@ -322,13 +305,11 @@ def _run_dense_leased(spec: ExperimentSpec, backend: ComponentSpec,
         try:
             with profile_ctx(spec.profile_dir):
                 trace = sim.run(x0, spec.T, eval_every=spec.eval_every,
-                                seed=spec.seed, loop=loop)
+                                seed=spec.seed)
         finally:
             sim.tracer = None
         wall = time.perf_counter() - t0
         metrics_fields.update(compile_s=sim.last_timings["compile_s"])
-        if sim.last_timings["eval_s"]:
-            metrics_fields.update(eval_s=sim.last_timings["eval_s"])
 
     # execute_s is defined as the non-compile remainder of the backend
     # wall, so compile_s + execute_s == wall_s exactly (JSON back-compat:
@@ -358,106 +339,6 @@ def _run_dense_leased(spec: ExperimentSpec, backend: ComponentSpec,
                      eps_value=eps_value, time_to_target=tta,
                      predictions=predictions, extras=extras,
                      metrics=metrics)
-
-
-def _dense_adaptive_run(sim: DDASimulator, ctrl, x0, T: int,
-                        eval_every: int, seed: int,
-                        timer: Callable[[], float] = time.perf_counter,
-                        timings: dict[str, Any] | None = None
-                        ) -> SimTrace:
-    """DDASimulator.run with the measure->predict->act loop on wall-clock.
-
-    Mirrors the plain segment loop but splits each evaluation segment into
-    uniform-comm chunks, dispatches each chunk through the scanned segment
-    program's AOT compile cache (`DDASimulator._get_compiled`, shape-keyed;
-    the comm mask is data), times every chunk on the host clock (blocking
-    on device completion), feeds `DenseController.observe`, and lets the
-    controller splice a re-solved h at each segment boundary -- the
-    frontier is `done`, the number of iterations already executed, so the
-    splice only shapes masks not yet built.
-
-    Compiling AOT *outside* the timed window is what keeps the controller's
-    measurements clean: timing a compile-bearing call would poison
-    t_plain/t_comm by orders of magnitude (with h0=1 the single t=1 plain
-    chunk is the ONLY plain sample until the first retune, and a
-    compile-inflated t_plain latches r_hat at 0 forever). The compiled
-    executables land in the same `sim._compiled` cache `run`/`run_batch`
-    use, so a warm simulator (e.g. held by the serving layer's compile
-    cache) pays no compile at all -- adaptive runs ride the same warm
-    executables as packable plain runs. (Earlier revisions instead warmed
-    the jit cache on a discarded duplicate call, paying one full chunk of
-    wasted compute per new chunk length.)
-
-    `timings` (optional dict) receives the observability record: the AOT
-    compile walls (always on the REAL clock -- the injected `timer` only
-    drives the controller's measurements) accumulate into
-    `timings["compile_s"]`, and each iteration's measured wall appends to
-    `timings["iter_walls"]`.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    n, k = sim.graph.n, sim.graph.degree
-    r_eff = sim.r * sim.wire_ratio(int(np.prod(x0.shape[1:])))
-    ctrl.bind(n, k, sim.graph.lambda2())
-    sched = sim.schedule
-    z = jnp.zeros_like(x0)
-    x = x0
-    xhat = x0
-    res = jnp.zeros_like(x0)
-    t = jnp.asarray(0.0, jnp.float32)
-    trace = SimTrace([], [], [], [], [])
-    res_norms: list[float] = []
-    sim_time = 0.0
-    comm_total = 0
-    root = jax.random.PRNGKey(seed)
-
-    done = 0
-    while done < T:
-        seg_end = min(done + eval_every, T)
-        while done < seg_end:
-            comm = sched.is_comm_step(done + 1)
-            chunk = 1
-            while (done + chunk < seg_end
-                   and sched.is_comm_step(done + chunk + 1) == comm):
-                chunk += 1
-            mask = np.full(chunk, comm)
-            keys = jax.random.split(jax.random.fold_in(root, done), chunk)
-            args = (z, x, xhat, res, t, jnp.asarray(mask), keys)
-            tw = time.perf_counter()
-            entry = sim._get_compiled(("segment",), sim._segment, args)
-            if timings is not None:
-                timings["compile_s"] += time.perf_counter() - tw
-            fn = sim._segment if entry is None else entry
-            t0 = timer()
-            z, x, xhat, res, t = fn(*args)
-            jax.block_until_ready(xhat)
-            per_iter = max(timer() - t0, 0.0) / chunk
-            if timings is not None:
-                timings["iter_walls"].extend([per_iter] * chunk)
-            for _ in range(chunk):
-                ctrl.observe(per_iter, comm)
-            done += chunk
-            if comm:
-                comm_total += chunk
-                sim_time += chunk * (1.0 / n + k * r_eff)
-            else:
-                sim_time += chunk * (1.0 / n)
-        xbar = jnp.mean(xhat, axis=0)
-        trace.iters.append(done)
-        trace.sim_time.append(sim_time)
-        trace.fvals.append(float(jnp.mean(jax.vmap(sim.eval_fn)(xhat))))
-        trace.fvals_consensus.append(float(sim.eval_fn(xbar)))
-        trace.comms.append(comm_total)
-        trace.disagreement.append(float(_cons.disagreement(z)))
-        if sim.compression is not None:
-            res_norms.append(float(jnp.mean(jnp.linalg.norm(
-                res.reshape(n, -1), axis=1))))
-        if done < T:  # a splice at the frontier T would shape zero
-            ctrl.maybe_retune(done)  # iterations: don't record phantoms
-    sim.last_res_norms = (np.asarray(res_norms)
-                          if sim.compression is not None else None)
-    return trace
 
 
 # ---------------------------------------------------------------------------
@@ -907,13 +788,8 @@ def batch_compat_report(spec: ExperimentSpec,
         return "profiling wants one run per capture"
     if spec.faults is not None:
         return "fault injection is event-driven (netsim backends only)"
-    params = dict(backend.params)
-    params.pop("compress_keep", None)
-    params.pop("mix", None)
-    if params.pop("loop", "scan") != "scan":
-        return "loop='segment' is the host-loop baseline (one lane per run)"
-    if params:
-        return f"dense backend has unknown params {sorted(params)}"
+    if backend.params:
+        return f"dense backend has unknown params {sorted(backend.params)}"
     if spec.stepsize.kind == "inv_sqrt":
         return 'stepsize "inv_sqrt" is host-only; lanes need the jnp path'
     problem = _build_problem(spec)

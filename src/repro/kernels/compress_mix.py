@@ -11,8 +11,7 @@ writes an (n, M) intermediate. This kernel fuses the mask multiply into
 the same VMEM-resident accumulation pass `gossip_mix_weighted` uses --
 the compress step rides along for free on an op that is purely
 bandwidth-bound, which is exactly the regime where top-k/rand-k messages
-would otherwise have forced the dense O(n^2 d) matmul split
-(`DDASimulator`'s old `compress_keep`-disables-sparse restriction).
+would otherwise have forced the dense O(n^2 d) matmul split.
 
 Layout mirrors `gossip_mix.gossip_mix_weighted`: (8, 1024) data tiles
 over (nodes, dims), the k neighbor message AND mask stacks as leading-dim

@@ -1073,8 +1073,7 @@ class VectorizedEngine:
         Returns ((n, k) slot weights, (n,) self weights), folded through
         the shared `core.graphs.mix_weight_slots` convention (W[i, src] /
         multiplicity per slot) -- the same fold `AsyncDDANode._stale_mix`
-        and the dense simulator's sparse gossip apply, keeping the engines
-        and execution modes equivalent. Cached on the (W, S_in) object
+        applies, keeping the engines equivalent. Cached on the (W, S_in) object
         pair: a retune installs a new W, a rewire a new S_in; both
         invalidate.
         """

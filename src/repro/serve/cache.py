@@ -23,8 +23,8 @@ that changes what gets compiled or the constants baked into it:
     MASK, which is *data* to the scanned program, not shape. The kind
     stays in the key per the issue's contract; note "every" vs "periodic"
     also picks the cond-free all-comm program variant;
-  * the resolved backend component (mix / loop / compress_keep shape the
-    program realization);
+  * the resolved backend component (its params shape a launch program;
+    the dense backend takes none);
   * controller presence/params (an adaptive run drives the per-segment
     program; a plain run drives the whole-run scan).
 

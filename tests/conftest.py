@@ -1,4 +1,7 @@
+import contextlib
 import os
+
+import pytest
 
 # Tests run on the default single CPU device (the dry-run sets its own
 # device count in its own process). Keep hypothesis deterministic.
@@ -15,3 +18,21 @@ if settings is not None:
     settings.register_profile("ci", max_examples=25, deadline=None,
                               derandomize=True)
     settings.load_profile("ci")
+
+
+@pytest.fixture
+def dense_mix(monkeypatch):
+    """A context manager under which a new `DDASimulator` takes the dense
+    P @ z matmul whatever its graph: the reference the tests hold the
+    sparse gather to. The simulator picks its mix from the graph; only a
+    test overrides that."""
+    from repro.core.dda import DDASimulator
+
+    @contextlib.contextmanager
+    def forced():
+        with monkeypatch.context() as m:
+            m.setattr(DDASimulator, "_resolve_mix_mode",
+                      lambda self: "dense")
+            yield
+
+    return forced

@@ -260,17 +260,20 @@ def test_topk_on_kregular_graph_stays_sparse():
     assert sim.wire_ratio(d) == TopK(keep=0.25).wire_ratio(d)
 
 
-def test_sparse_vs_dense_mix_identical_under_compression():
+def test_sparse_vs_dense_mix_identical_under_compression(dense_mix):
     """The fused sparse path and the forced dense-matmul path run the SAME
     compressed algorithm: traces agree to float tolerance."""
     n, d, T = 12, 10, 80
     subgrad, obj = _quadratic(n, d)
     g = kregular_expander(n, k=4, seed=0)
+    make = lambda: DDASimulator(subgrad, obj, g, EveryIteration(),
+                                a_fn=stepsize_sqrt(0.1),
+                                compression=TopK(keep=0.25))
+    sims = {"sparse": make()}
+    with dense_mix():
+        sims["dense"] = make()
     traces = {}
-    for mix in ("sparse", "dense"):
-        sim = DDASimulator(subgrad, obj, g, EveryIteration(),
-                           a_fn=stepsize_sqrt(0.1), mix=mix,
-                           compression=TopK(keep=0.25))
+    for mix, sim in sims.items():
         assert sim.mix_mode == mix
         traces[mix] = sim.run(jnp.zeros((n, d)), T, eval_every=20)
     a = np.asarray(traces["sparse"].fvals)

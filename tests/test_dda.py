@@ -6,9 +6,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.compress import TopK
 from repro.core import (DDASimulator, EveryIteration, IncreasinglySparse,
-                        Periodic, complete_graph, dda_init, dda_local_step,
-                        ring_graph, stepsize_sqrt)
+                        Periodic, complete_graph, ring_graph, stepsize_sqrt)
 
 
 def _quadratic_problem(n=6, d=8, seed=0):
@@ -81,7 +81,7 @@ def test_dda_with_compression_converges():
     fstar = float(objective(jnp.mean(c, axis=0)))
     sim = DDASimulator(subgrad, jax.jit(objective), complete_graph(n),
                        EveryIteration(), a_fn=stepsize_sqrt(0.05),
-                       compress_keep=0.25)
+                       compression=TopK(keep=0.25))
     tr = sim.run(jnp.zeros((n, d)), 800, eval_every=800)
     assert tr.fvals[-1] < fstar * 1.15
 
@@ -98,19 +98,6 @@ def test_time_model_accounting():
     expected = 90 * (1 / n) + H * g.degree * r
     assert np.isclose(tr.sim_time[-1], expected, rtol=1e-6)
     assert tr.comms[-1] == H
-
-
-def test_dda_local_step_pure():
-    x0 = {"w": jnp.ones((3,))}
-    state = dda_init(x0)
-    grad = {"w": jnp.full((3,), 2.0)}
-    a_fn = stepsize_sqrt(0.1)
-    s1 = dda_local_step(state, grad, a_fn)
-    np.testing.assert_allclose(np.asarray(s1.z["w"]), 2.0)
-    np.testing.assert_allclose(np.asarray(s1.x["w"]), -0.1 * 2.0, rtol=1e-6)
-    # running average after first step equals x(1)
-    np.testing.assert_allclose(np.asarray(s1.xhat["w"]),
-                               np.asarray(s1.x["w"]), rtol=1e-6)
 
 
 def test_disagreement_decreases_with_communication():
@@ -139,78 +126,83 @@ def _rel(a, b):
     return float(np.max(np.abs(a - b) / np.maximum(np.abs(a), 1e-12)))
 
 
+def _reference_run(subgrad, objective, graph, sched, a_fn, r, x0, T,
+                   eval_every):
+    """Plain host DDA (eq. 3-5): the dense P, one iteration at a time, F
+    and the eq. 9 time charge at every `eval_every` and at T."""
+    n, k = graph.n, graph.degree
+    P = jnp.asarray(graph.mixing_matrix(), jnp.float32)
+    z, x, xhat = jnp.zeros_like(x0), x0, x0
+    iters, sim_time, comms, fvals, fvals_c = [], [], [], [], []
+    clock, H, last_t, last_H = 0.0, 0, 0, 0
+    for t in range(1, T + 1):
+        g = subgrad(x, jnp.float32(t - 1), None)
+        comm = sched.is_comm_step(t)
+        z = (P @ z if comm else z) + g
+        x = -a_fn(jnp.asarray(t, jnp.float32)) * z
+        xhat = ((t - 1) * xhat + x) / t
+        H += int(comm)
+        if t % eval_every == 0 or t == T:
+            clock += (t - last_t) * (1.0 / n) + (H - last_H) * k * r
+            last_t, last_H = t, H
+            iters.append(t)
+            sim_time.append(clock)
+            comms.append(H)
+            fvals.append(float(jnp.mean(jax.vmap(objective)(xhat))))
+            fvals_c.append(float(objective(jnp.mean(xhat, axis=0))))
+    return iters, sim_time, comms, fvals, fvals_c
+
+
 @pytest.mark.parametrize("sched", [EveryIteration(), Periodic(h=3),
                                    IncreasinglySparse(p=0.3)],
                          ids=["every", "h3", "p03"])
-def test_scan_loop_matches_segment_loop(sched):
-    """The fully-scanned run == the legacy per-segment dispatch loop on the
-    same simulator: identical time axis and comm counts, fvals equal to
-    float-fusion tolerance (eval moves inside jit). Covers a partial final
+def test_scan_run_matches_reference_loop(sched):
+    """The scanned run (sparse gather on the expander) == a plain host
+    loop of the algorithm with the dense P: identical time axis and comm
+    counts, fvals equal to float tolerance. Covers a partial final
     segment (T % eval_every != 0)."""
-    n, d = 6, 12
+    n, d, T, r = 6, 12, 103, 0.02
     subgrad, objective, _ = _quadratic_problem(n, d)
-    sim = DDASimulator(subgrad, jax.jit(objective), _expander(n, k=2),
-                       sched, a_fn=stepsize_sqrt(0.05), r=0.02)
-    seg = sim.run(jnp.zeros((n, d)), 103, eval_every=25, loop="segment")
-    scan = sim.run(jnp.zeros((n, d)), 103, eval_every=25, loop="scan")
-    assert seg.iters == scan.iters
-    assert seg.sim_time == scan.sim_time
-    assert seg.comms == scan.comms
-    assert _rel(seg.fvals, scan.fvals) < 1e-5
-    assert _rel(seg.fvals_consensus, scan.fvals_consensus) < 1e-5
+    graph = _expander(n, k=2)
+    sim = DDASimulator(subgrad, jax.jit(objective), graph, sched,
+                       a_fn=stepsize_sqrt(0.05), r=r)
+    scan = sim.run(jnp.zeros((n, d)), T, eval_every=25)
+    iters, sim_time, comms, fvals, fvals_c = _reference_run(
+        subgrad, objective, graph, sched, stepsize_sqrt(0.05), r,
+        jnp.zeros((n, d)), T, 25)
+    assert scan.iters == iters
+    assert scan.sim_time == sim_time
+    assert scan.comms == comms
+    assert _rel(scan.fvals, fvals) < 1e-5
+    assert _rel(scan.fvals_consensus, fvals_c) < 1e-5
 
 
-def test_sparse_mix_matches_dense_on_expander():
+def test_sparse_mix_matches_dense_on_expander(dense_mix):
     """The gather+fused sparse gossip path reproduces the dense-matmul mix
     on a seeded expander run to <= 1e-5 relative (the acceptance gate's
     tolerance; float accumulation order differs)."""
     n, d = 12, 24
     subgrad, objective, _ = _quadratic_problem(n, d, seed=1)
+    make = lambda: DDASimulator(subgrad, jax.jit(objective), _expander(n),
+                                EveryIteration(), a_fn=stepsize_sqrt(0.05))
+    sims = {"sparse": make()}
+    with dense_mix():
+        sims["dense"] = make()
     traces = {}
-    for mix in ("dense", "sparse"):
-        sim = DDASimulator(subgrad, jax.jit(objective), _expander(n),
-                           EveryIteration(), a_fn=stepsize_sqrt(0.05),
-                           mix=mix)
+    for mix, sim in sims.items():
         assert sim.mix_mode == mix
         traces[mix] = sim.run(jnp.zeros((n, d)), 150, eval_every=30)
     assert _rel(traces["dense"].fvals, traces["sparse"].fvals) < 1e-5
     assert traces["dense"].comms == traces["sparse"].comms
 
 
-def test_sparse_mix_weights_matches_dense_weighted():
-    """A reweighted edge-supported P (`mix_weights`, the
-    reweight_gossip shape) runs through the sparse per-edge path and
-    matches the dense matmul with the same W."""
-    n, d = 10, 16
-    subgrad, objective, _ = _quadratic_problem(n, d, seed=2)
-    g = _expander(n)
-    rng = np.random.default_rng(0)
-    W = g.mixing_matrix()
-    # perturb edge weights, fold the correction into the diagonal so rows
-    # stay stochastic (shape-wise; exact stochasticity is not required)
-    for i in range(n):
-        for j in range(n):
-            if i != j and W[i, j] != 0.0:
-                delta = rng.uniform(-0.3, 0.3) * W[i, j]
-                W[i, j] += delta
-                W[i, i] -= delta
-    traces = {}
-    for mix in ("dense", "sparse"):
-        sim = DDASimulator(subgrad, jax.jit(objective), g, Periodic(h=2),
-                           a_fn=stepsize_sqrt(0.05), mix=mix,
-                           mix_weights=W)
-        assert sim.mix_mode == mix
-        traces[mix] = sim.run(jnp.zeros((n, d)), 120, eval_every=30)
-    assert _rel(traces["dense"].fvals, traces["sparse"].fvals) < 1e-5
-
-
 def test_auto_mix_fallbacks():
-    """auto -> dense for complete graphs and for a mix_weights with weight
-    OUTSIDE the graph's edge support (non-regular P); forcing mix="sparse"
-    there raises. Compression does NOT disqualify sparse: compressed
-    messages ride the fused compress-mix gather."""
-    n, d = 8, 8
-    subgrad, objective, _ = _quadratic_problem(n, d)
+    """The mix follows the graph: sparse on an expander, dense on the
+    complete graph (a degree-(n-1) gather moves more memory than the
+    matmul). Compression does NOT disqualify sparse: compressed messages
+    ride the fused compress-mix gather."""
+    n = 8
+    subgrad, objective, _ = _quadratic_problem(n, 8)
     g = _expander(n)
     obj = jax.jit(objective)
     assert DDASimulator(subgrad, obj, g, EveryIteration()).mix_mode \
@@ -218,29 +210,16 @@ def test_auto_mix_fallbacks():
     assert DDASimulator(subgrad, obj, complete_graph(n),
                         EveryIteration()).mix_mode == "dense"
     assert DDASimulator(subgrad, obj, g, EveryIteration(),
-                        compress_keep=0.5).mix_mode == "sparse"
-    W = g.mixing_matrix()
-    W[0, :] = 1.0 / n  # weight on non-edges: not gatherable along edges
-    sim = DDASimulator(subgrad, obj, g, EveryIteration(), mix_weights=W)
-    assert sim.mix_mode == "dense"
-    with pytest.raises(ValueError, match="edge support"):
-        DDASimulator(subgrad, obj, g, EveryIteration(), mix_weights=W,
-                     mix="sparse")
-    # the dense fallback actually APPLIES the override
-    tr_w = sim.run(jnp.zeros((n, d)), 40, eval_every=40)
-    tr_p = DDASimulator(subgrad, obj, g, EveryIteration()).run(
-        jnp.zeros((n, d)), 40, eval_every=40)
-    assert tr_w.fvals != tr_p.fvals
+                        compression=TopK(keep=0.5)).mix_mode == "sparse"
 
 
 def test_scan_loop_empty_run():
-    """T=0 returns an empty trace on every loop, as the legacy path did."""
+    """T=0 returns an empty trace, from `run` and every `run_batch` lane."""
     n, d = 4, 4
     subgrad, objective, _ = _quadratic_problem(n, d)
     sim = DDASimulator(subgrad, jax.jit(objective), _expander(n, k=2))
-    for loop in ("scan", "segment"):
-        tr = sim.run(jnp.zeros((n, d)), 0, eval_every=10, loop=loop)
-        assert tr.iters == [] and tr.fvals == []
+    tr = sim.run(jnp.zeros((n, d)), 0, eval_every=10)
+    assert tr.iters == [] and tr.fvals == []
     batch = sim.run_batch(jnp.zeros((n, d)), 0, 10,
                           np.zeros((2, 0), bool), seeds=[0, 1])
     assert all(tr.iters == [] for tr in batch)
@@ -266,6 +245,49 @@ def test_run_batch_matches_single_runs():
         assert btr.comms == tr.comms
         assert _rel(btr.fvals, tr.fvals) < 1e-6
         assert _rel(btr.disagreement, tr.disagreement) < 1e-5
+
+
+class _SteadyController:
+    """The three calls `run_adaptive` makes, recorded; never retunes."""
+
+    def __init__(self):
+        self.bound, self.observed, self.frontiers = None, [], []
+
+    def bind(self, n, k, lam2):
+        self.bound = (n, k, lam2)
+
+    def observe(self, per_iter, comm):
+        self.observed.append(bool(comm))
+
+    def maybe_retune(self, done):
+        self.frontiers.append(done)
+
+
+@pytest.mark.parametrize("sched", [EveryIteration(), Periodic(h=3)],
+                         ids=["every", "h3"])
+def test_run_adaptive_without_retunes_matches_run(sched):
+    """With a controller that never retunes, the chunked wall-clock loop
+    runs the same algorithm as the scanned run: same iterations and comm
+    counts, the time axis and F to float tolerance (its charges and its
+    evaluation are summed in another order). The controller sees one
+    observation per iteration and a frontier at every segment end but T."""
+    n, d, T, r = 6, 12, 103, 0.02
+    subgrad, objective, _ = _quadratic_problem(n, d)
+    graph = _expander(n, k=2)
+    sim = DDASimulator(subgrad, jax.jit(objective), graph, sched,
+                       a_fn=stepsize_sqrt(0.05), r=r)
+    want = sim.run(jnp.zeros((n, d)), T, eval_every=25)
+    ctrl = _SteadyController()
+    got = sim.run_adaptive(jnp.zeros((n, d)), T, 25, 0, ctrl)
+    assert got.iters == want.iters
+    assert got.comms == want.comms
+    assert _rel(got.sim_time, want.sim_time) < 1e-12
+    assert _rel(got.fvals, want.fvals) < 1e-5
+    assert _rel(got.fvals_consensus, want.fvals_consensus) < 1e-5
+    assert ctrl.bound == (n, graph.degree, graph.lambda2())
+    assert ctrl.observed == list(sched.comm_mask(0, T))
+    assert ctrl.frontiers == [25, 50, 75, 100]
+    assert sim.last_res_norms is None
 
 
 @pytest.mark.parametrize("schedule", [{"kind": "every"},

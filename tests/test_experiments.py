@@ -258,6 +258,26 @@ def test_backend_rejects_unknown_params_and_bad_combos():
         run(tiny_netsim_spec("marshy"))
 
 
+@pytest.mark.parametrize("param,value", [("mix", "dense"),
+                                         ("loop", "segment"),
+                                         ("compress_keep", 0.5)],
+                         ids=["mix", "loop", "compress_keep"])
+def test_dense_backend_rejects_removed_params(param, value):
+    """The dense backend takes no params: a manifest that still sets the
+    mix, loop or compress_keep param of older revisions fails with the
+    unknown-params error, in a solo run and in the lane packer's report,
+    instead of being silently ignored."""
+    from repro.experiments.runner import batch_compat_report
+
+    d = json.loads(tiny_netsim_spec().to_json())
+    d["stepsize"] = {"kind": "sqrt", "params": {"A": 0.5}}
+    d["backends"] = [{"kind": "dense", "params": {param: value}}]
+    spec = ExperimentSpec.from_json(json.dumps(d))
+    with pytest.raises(ValueError, match=rf"unknown params \['{param}'\]"):
+        run(spec)
+    assert "unknown params" in batch_compat_report(spec, spec.backends[0])
+
+
 # ---------------------------------------------------------------------------
 # checked-in manifests: every declared backend runs
 # ---------------------------------------------------------------------------
@@ -319,7 +339,6 @@ def test_dense_adaptive_retunes_from_injected_timings(monkeypatch):
     from repro.adaptive import AdaptiveSchedule, DenseController
     from repro.core import DDASimulator, complete_graph
     from repro.core.dda import stepsize_sqrt
-    from repro.experiments.runner import _dense_adaptive_run
 
     prob = problems.build("quadratic_consensus", n=8, d=4, seed=0)
     sched = AdaptiveSchedule(h0=1)
@@ -350,8 +369,8 @@ def test_dense_adaptive_retunes_from_injected_timings(monkeypatch):
     monkeypatch.setattr(sim, "_segment", charged_segment)
     import jax.numpy as jnp
     ctrl = DenseController(sched, warmup_comm=2)
-    trace = _dense_adaptive_run(sim, ctrl, jnp.zeros((8, 4)), T=200,
-                                eval_every=20, seed=0, timer=clock)
+    trace = sim.run_adaptive(jnp.zeros((8, 4)), T=200, eval_every=20,
+                             seed=0, controller=ctrl, timer=clock)
     assert trace.iters[-1] == 200
     # constant injected timings -> the EW means are exact, and inverting
     # eq. 9 recovers the injected r exactly: t_msg = (t_comm - t_plain)/k
@@ -375,8 +394,8 @@ def test_dense_adaptive_retunes_from_injected_timings(monkeypatch):
 
     monkeypatch.setattr(sim2, "_segment", charged_segment2)
     ctrl2 = DenseController(sched2, warmup_comm=2)
-    _dense_adaptive_run(sim2, ctrl2, jnp.zeros((8, 4)), T=200,
-                        eval_every=20, seed=0, timer=clock2)
+    sim2.run_adaptive(jnp.zeros((8, 4)), T=200, eval_every=20, seed=0,
+                      controller=ctrl2, timer=clock2)
     assert sched2.h_current > 1, "expensive comm must raise h"
     assert sched2.retunes, "a retune must be recorded"
 

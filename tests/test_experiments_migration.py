@@ -275,13 +275,13 @@ def test_dense_cell_bit_identical():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("compress_keep", [None, 0.5],
-                         ids=["exact", "compressed"])
-def test_fig1_cells_bit_identical(compress_keep):
+@pytest.mark.parametrize("keep", [None, 0.5], ids=["exact", "compressed"])
+def test_fig1_cells_bit_identical(keep):
     import jax
     import jax.numpy as jnp
 
     from benchmarks.fig1_complete import cell_spec
+    from repro.compress import TopK
     from repro.core import DDASimulator, EveryIteration, complete_graph
     from repro.core.dda import stepsize_sqrt
     from repro.experiments.components import problems
@@ -292,14 +292,13 @@ def test_fig1_cells_bit_identical(compress_keep):
     sim = DDASimulator(prob.subgrad_stack, jax.jit(prob.objective),
                        complete_graph(n), EveryIteration(),
                        a_fn=stepsize_sqrt(A), projection=prob.projection,
-                       r=r, compress_keep=compress_keep)
+                       r=r, compression=None if keep is None
+                       else TopK(keep=keep))
     legacy_trace = sim.run(jnp.zeros((n, prob.d)), T, eval_every=10,
                            seed=seed)
 
-    res = run(cell_spec(n, m_pairs, d, T, A, r, seed,
-                        compress_keep=compress_keep))
-    _assert_traces_equal(legacy_trace, res.trace,
-                         f"fig1 compress={compress_keep}")
+    res = run(cell_spec(n, m_pairs, d, T, A, r, seed, keep=keep))
+    _assert_traces_equal(legacy_trace, res.trace, f"fig1 keep={keep}")
 
 
 def test_fig1_reduced_applies_byte_ratio():

@@ -1,5 +1,5 @@
-"""Fault tolerance: deadline gossip, straggler robustness, elastic rescale,
-and message compression with error feedback."""
+"""Fault tolerance: deadline gossip, straggler robustness and elastic
+rescale."""
 
 import jax
 import jax.numpy as jnp
@@ -7,8 +7,6 @@ import numpy as np
 import pytest
 from _hyp import given, st
 
-from repro.core import (CompressionState, complete_graph, ef_compress,
-                        ef_init, mix_dense, ratio_bytes, ring_graph)
 from repro.core.graphs import random_regular_expander
 from repro.runtime.elastic import plan_rescale, rescale_state
 from repro.runtime.fault_tolerance import StragglerModel, degraded_matrix
@@ -108,24 +106,3 @@ def test_elastic_rescale_grow_from_single_survivor():
     assert out["w"].shape == (4, 2)
     for i in range(4):
         np.testing.assert_allclose(np.asarray(out["w"][i]), [2, 3])
-
-
-def test_error_feedback_accumulates_everything():
-    """Over T rounds, sum(sent) + residual == sum(messages): EF loses
-    nothing permanently."""
-    rng = np.random.default_rng(0)
-    msgs = [jnp.asarray(rng.normal(size=(32,)), jnp.float32)
-            for _ in range(10)]
-    state = ef_init(msgs[0])
-    total_sent = jnp.zeros(32)
-    for m in msgs:
-        sent, state = ef_compress(m, state, keep_fraction=0.1)
-        total_sent = total_sent + sent
-    total_msgs = sum(msgs)
-    np.testing.assert_allclose(np.asarray(total_sent + state.residual),
-                               np.asarray(total_msgs), atol=1e-5)
-
-
-def test_ratio_bytes():
-    assert np.isclose(ratio_bytes(0.01, 4, 4), 0.02)
-    assert np.isclose(ratio_bytes(0.05, 8, 4), 0.075)
